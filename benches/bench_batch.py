@@ -82,7 +82,10 @@ def main() -> None:
             import jax
 
             jax.config.update("jax_platforms", args.platform)
+        from cpzk_tpu import jaxrt
         from cpzk_tpu.ops.backend import TpuBackend
+
+        jaxrt.enable_compile_cache()
 
         backends.append(("tpu", TpuBackend()))
 

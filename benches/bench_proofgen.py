@@ -29,9 +29,11 @@ def main() -> None:
 
         jax.config.update("jax_platforms", args.platform)
 
-    from cpzk_tpu import Parameters, SecureRng
+    from cpzk_tpu import Parameters, SecureRng, jaxrt
     from cpzk_tpu.core.ristretto import Ristretto255
     from cpzk_tpu.ops.prove import BatchProver
+
+    jaxrt.enable_compile_cache()
 
     rng = SecureRng()
     bp = BatchProver(Parameters.new())

@@ -93,6 +93,7 @@ class DeviceSink:
     compiled: list[str] = field(default_factory=list)
     rows: int = 0
     lanes: int = 0
+    combined: bool | None = None
 
 
 _SINK: contextvars.ContextVar[DeviceSink | None] = contextvars.ContextVar(
@@ -137,6 +138,16 @@ def note_jit(shape: str, first_sight: bool) -> None:
             sink.jit_hits += 1
 
 
+def note_combined(accepted: bool) -> None:
+    """Outcome of the batch's combined RLC check on the device: a reject
+    sends the batch to per-row ``verify_each`` (twice the device work), so
+    a combined check that rejects valid batches is invisible in verdicts
+    and visible only here."""
+    sink = _SINK.get()
+    if sink is not None:
+        sink.combined = accepted
+
+
 def note_lanes(rows: int, lanes: int) -> None:
     """Padded device-lane accounting for the current dispatch: occupancy
     = true rows / padded lanes (the complement of ``tpu.batch.pad_waste``)."""
@@ -171,6 +182,7 @@ class FlightRecord:
     jit_hits: int = 0
     jit_misses: int = 0
     compiled: list[str] = field(default_factory=list)
+    combined: bool | None = None  # combined check accepted (None: not run)
 
     def stage_sum_s(self) -> float:
         """Sum of the widened stage spans — the tests pin this against
@@ -193,6 +205,7 @@ class FlightRecord:
             "dispatch_gap_s": self.dispatch_gap_s,
             "jit_hits": self.jit_hits,
             "jit_misses": self.jit_misses,
+            "combined": self.combined,
             "compiled": list(self.compiled),
         }
 

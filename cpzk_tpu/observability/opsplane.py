@@ -11,7 +11,8 @@ starts **before** the gRPC listener, serving:
 - ``GET /metrics``  — text exposition rendered directly from the metrics
   facade's own registry (:func:`cpzk_tpu.server.metrics.render_exposition`),
   identical family set on the prometheus and no-prometheus backings;
-- ``GET /statusz``  — one JSON snapshot of the whole box: batcher depth/
+- ``GET /statusz``  — one JSON snapshot of the whole box: the jax device
+  it serves on (platform, kind, count, native core), batcher depth/
   in-flight/drain rate, dispatch-lane stage percentiles from the flight
   ring, per-shard registry sizes + sampled lock wait, admission level,
   breaker state, replication role/epoch/lag/last ship, audit log
@@ -82,6 +83,7 @@ class OpsSources:
     fleet: object | None = None        # fleet.FleetRouter
     ingest: object | None = None       # server.ingest.IngestSupervisor
     controller: object | None = None   # fleet.controller.FleetController
+    device: object | None = None       # () -> dict: the jax device statement
     config_fingerprint: str = ""
     role: str = "server"               # "server" | "standby" | "audit"
     started_at: float = field(default_factory=time.monotonic)
@@ -111,6 +113,11 @@ class OpsSources:
             "config_fingerprint": self.config_fingerprint,
             "ts": time.time(),
         }
+
+        # which device this process serves on (platform, kind, count,
+        # native core, per-device allocator bytes): the chip smoke's check
+        # that --backend tpu did not quietly land on XLA CPU
+        doc["device"] = self.device() if self.device is not None else None
 
         batcher = self.batcher
         if batcher is not None:

@@ -472,6 +472,7 @@ class BatchStages:
             jit_hits=sink.jit_hits,
             jit_misses=sink.jit_misses,
             compiled=list(sink.compiled),
+            combined=sink.combined,
         )
         return flightrec.get_flight_recorder().record(rec)
 

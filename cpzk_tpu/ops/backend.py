@@ -53,7 +53,7 @@ from . import curve, msm, verify
 PIPPENGER_MIN_ROWS = int(os.environ.get("CPZK_PIPPENGER_MIN", str(1 << 62)))
 
 #: Maximum lane count for one monolithic device program.  Measured on TPU
-#: v5 lite (benches/debug_pip16k.py, PROFILE.md §7a): the MSM kernel is
+#: v5 lite (round-5 sweep, PROFILE.md §7a): the MSM kernel is
 #: bit-correct through 32,770 lanes and deterministically WRONG at 40,962+
 #: (internal XLA error at 49,154; all-zero output at 57,346), and the
 #: per-row combined kernel fails its in-kernel check at 65,538 rows while
@@ -141,6 +141,16 @@ def _note_marshal(t0: float) -> None:
         pass
 
 
+def _note_combined(ok: bool) -> None:
+    """Combined-check outcome into the flight recorder's device sink."""
+    try:
+        from ..observability import flightrec
+
+        flightrec.note_combined(ok)
+    except Exception:  # pragma: no cover - observability unavailable
+        pass
+
+
 #: Per-thread device pin.  A per-device dispatch lane's backend enters
 #: :func:`device_scope` around every verify call, which (a) makes
 #: ``jax.default_device`` target that chip for the thread (staging
@@ -209,6 +219,13 @@ def _jit_first_sight(*key) -> bool:
     return first
 
 
+def _mark_seen(key: tuple) -> None:
+    """Book a program compiled before ready (prewarm): its first serving
+    dispatch is a jit HIT."""
+    with _JIT_LOCK:
+        _JIT_SEEN.add(_scoped_key(key))
+
+
 #: Pre-lowered executables per (kernel, padded shape[, device]), keyed
 #: like ``_JIT_SEEN``.  Populated by :func:`prewarm_executables` at server
 #: startup (``[tpu] prewarm_quanta``) via ``jit(...).lower(...).compile()``;
@@ -228,12 +245,9 @@ def _aot_get(*key):
 
 
 def _aot_register(key: tuple, exe) -> None:
-    key = _scoped_key(key)
     with _JIT_LOCK:
-        _AOT_CACHE[key] = exe
-        # pre-register the jit cache key: the first serving dispatch at
-        # this shape (on this device) is a HIT (compiled before ready)
-        _JIT_SEEN.add(key)
+        _AOT_CACHE[_scoped_key(key)] = exe
+    _mark_seen(key)
 
 
 def _point_aval(pad: int):
@@ -326,10 +340,7 @@ def prewarm_executables(batch_sizes, devices=None) -> list[str]:
     ``devices`` targets the prewarm: ``None`` warms the default device
     with the historical unsuffixed cache keys; a device list compiles one
     executable PER device under :func:`device_scope`, so every per-device
-    dispatch lane's first serving dispatch books a jit HIT (before this,
-    prewarm registered ``_JIT_SEEN`` globally but compiled on the default
-    device only — lanes 1..N-1 ate a first-dispatch compile the recorder
-    then misbooked as a cache hit).
+    dispatch lane's first serving dispatch books a jit HIT.
 
     Returns the warmed shape keys (for the startup log).  Idempotent per
     (shape, device)."""
@@ -337,21 +348,26 @@ def prewarm_executables(batch_sizes, devices=None) -> list[str]:
     for device in (devices if devices is not None else [None]):
         with device_scope(device):
             for key, lower in _prewarm_plan(batch_sizes):
-                if _aot_get(*key) is not None:
-                    continue
-                t0 = time.perf_counter()
-                exe = lower().compile()
-                _aot_register(key, exe)
-                name = "/".join(str(k) for k in _scoped_key(key))
-                warmed.append(name)
-                log_s = time.perf_counter() - t0
-                if log_s > 1.0:  # long compiles are worth a line each
-                    import logging
-
-                    logging.getLogger("cpzk_tpu.ops.backend").info(
-                        "prewarmed %s in %.1fs", name, log_s
-                    )
+                if _aot_get(*key) is None:
+                    _aot_register(
+                        key, _timed_compile(key, lambda: lower().compile()))
+                    warmed.append("/".join(str(k) for k in _scoped_key(key)))
     return warmed
+
+
+def _timed_compile(key: tuple, compile_):
+    """``compile_()``, with a log line for a long compile."""
+    t0 = time.perf_counter()
+    exe = compile_()
+    log_s = time.perf_counter() - t0
+    if log_s > 1.0:  # long compiles are worth a line each
+        import logging
+
+        logging.getLogger("cpzk_tpu.ops.backend").info(
+            "prewarmed %s in %.1fs",
+            "/".join(str(k) for k in _scoped_key(key)), log_s,
+        )
+    return exe
 
 
 def _pad_pow2(n: int) -> int:
@@ -370,6 +386,30 @@ def _pad_lanes(n: int) -> int:
     if n <= q:
         return _pad_pow2(n)
     return -(-n // q) * q
+
+
+def _msm_shape(n: int) -> tuple[int, int]:
+    """(c, m_pad) of the combined Pippenger MSM over ``n`` rows: 4n+2
+    terms (four per row, plus the G and H correction terms) in
+    4 * pow2(n) slots, or 4 * pow2(n) + 2 when n is itself a power of
+    two.  Whole power-of-two term counts tile into whole chunks and mesh
+    slices: the old 4 * pow2(n) + 2 spilled the two correction terms
+    into one more, near-empty remainder program — a second sharded MSM
+    compile for every batch past one mesh step."""
+    m = 4 * _pad_pow2(n)
+    if 4 * n + 2 > m:
+        m += 2
+    # window size is per-PROGRAM: past the chunk cap the MSM runs as
+    # LANE_CHUNK-term tiles (chunked_msm_identity) and each device of
+    # a mesh sees at most LANE_CHUNK lanes (_mesh_step), so the cost
+    # model must see the chunk length, not the full term count —
+    # sizing from m overshot c by 2 windows at 64k terms (ADVICE.md /
+    # ROADMAP item 4 calibration-tail fix)
+    c = msm.pick_window(min(m, LANE_CHUNK))
+    # m is already shape-quantized, so below the chunk cap it is used
+    # EXACTLY; above it, quantum padding keeps the waste to under one
+    # LANE_QUANTUM of identity terms
+    return c, (m if m <= LANE_CHUNK else _pad_lanes(m))
 
 
 def _chunk_bounds(pad: int):
@@ -779,11 +819,34 @@ class TpuBackend(VerifierBackend):
         _note_gh_cache(size, evicted)
         return pair
 
+    def prewarm(self, batch_sizes) -> list[str]:
+        """Compile every program this instance dispatches for the given
+        batch sizes before serving: the sharded MSM (with its partials
+        reduction) and sharded ``verify_each`` under a mesh, else the
+        single-device kernels (:func:`prewarm_executables`).  Returns
+        the warmed program names; their first dispatch books a jit HIT."""
+        if self._mesh is None:
+            return prewarm_executables(
+                batch_sizes,
+                devices=None if self._device is None else [self._device])
+        warmed: list[str] = []
+        for n in map(int, batch_sizes):
+            c, m_pad = _msm_shape(n)
+            warmed += _timed_compile(
+                ("mesh_msm", c, m_pad),
+                lambda: self._sharded_msm.warm(m_pad, c))
+            warmed += _timed_compile(
+                ("mesh_each", n),
+                lambda: self._sharded_each.warm(_pad_lanes(n)))
+        return warmed
+
     # -- VerifierBackend interface ------------------------------------------
 
     def verify_combined(self, rows: list[BatchRow], beta: Scalar) -> bool:
         with device_scope(self._device):
-            return self._verify_combined(rows, beta)
+            ok = self._verify_combined(rows, beta)
+        _note_combined(ok)
+        return ok
 
     def _verify_combined(self, rows: list[BatchRow], beta: Scalar) -> bool:
         n = len(rows)
@@ -857,18 +920,7 @@ class TpuBackend(VerifierBackend):
             + [r.y2 for r in rows]
             + [rows[0].g, rows[0].h]
         )
-        m = 4 * _pad_pow2(len(rows)) + 2
-        # window size is per-PROGRAM: past the chunk cap the MSM runs as
-        # LANE_CHUNK-term tiles (chunked_msm_identity) and each device of
-        # a mesh sees at most LANE_CHUNK lanes (_mesh_step), so the cost
-        # model must see the chunk length, not the full term count —
-        # sizing from m overshot c by 2 windows at 64k terms (ADVICE.md /
-        # ROADMAP item 4 calibration-tail fix)
-        c = msm.pick_window(min(m, LANE_CHUNK))
-        # m is already shape-quantized (4*pow2+2), so below the chunk cap
-        # it is used EXACTLY; above it, quantum padding keeps the waste to
-        # under one LANE_QUANTUM of identity terms
-        m_pad = m if m <= LANE_CHUNK else _pad_lanes(m)
+        c, m_pad = _msm_shape(len(rows))
         _note_pad_waste(4 * len(rows) + 2, m_pad)
         pts = _elems_soa(elems, m_pad, device=self._device)
         if device_rlc:

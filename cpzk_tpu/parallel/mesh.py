@@ -17,22 +17,14 @@ per-proof kernel and contribute the identity to the combined sum.
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8 top-level API (check_vma); experimental kept for older jax
-    from jax import shard_map as _new_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _new_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import curve, msm, verify
 
@@ -113,7 +105,7 @@ def pad_windows(w: jnp.ndarray, n_to: int) -> jnp.ndarray:
     )
 
 
-def _mesh_step(d: int, n: int) -> tuple[int, int]:
+def _mesh_pad(d: int, n: int) -> tuple[int, int]:
     """(step, n_to): the per-slice lane count d*LANE_CHUNK that keeps every
     per-device program at or under the TPU large-lane miscompile bound
     (ops/backend.py LANE_CHUNK), and the padded total.  Single source for
@@ -138,8 +130,20 @@ def _mesh_step(d: int, n: int) -> tuple[int, int]:
         per_device = -(-n // d)               # ceil lanes per device
         per_device = -(-per_device // q) * q  # quantum-align its program
         n_to = per_device * d
+    return step, n_to
+
+
+def _mesh_step(d: int, n: int) -> tuple[int, int]:
+    """:func:`_mesh_pad` for a dispatch: also books the lane occupancy."""
+    step, n_to = _mesh_pad(d, n)
     _note_occupancy(n, n_to)
     return step, n_to
+
+
+def _slices(step: int, n_to: int) -> list[tuple[int, int]]:
+    """(lo, hi) mesh slices of a padded lane axis: full steps plus one
+    shorter (d-multiple) remainder."""
+    return [(lo, min(lo + step, n_to)) for lo in range(0, n_to, step)]
 
 
 def _note_occupancy(n: int, n_to: int) -> None:
@@ -154,6 +158,66 @@ def _note_occupancy(n: int, n_to: int) -> None:
         pass
 
 
+class _Programs:
+    """The AOT-compiled programs of one sharded wrapper, one per input
+    shape, compiled at most once (``jit(...).lower(...).compile()``, the
+    single-device AOT cache's scheme).  Every dispatch is booked with the
+    flight recorder's jit counters under ``(name, d, shape...)``: a
+    program :meth:`warm` compiled before ready is a HIT, one compiled on
+    first sight while serving is a MISS."""
+
+    def __init__(self, name: str, mesh: Mesh, fn, in_specs):
+        self._name = name
+        self._d = mesh.devices.size
+        self._fn = fn
+        self._shardings = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), in_specs,
+            is_leaf=lambda x: isinstance(x, P))
+        self._exes: dict[tuple, object] = {}
+        self._lock = threading.Lock()
+
+    def _exe(self, key: tuple, avals):
+        with self._lock:  # pipelined batches dispatch from worker threads
+            exe = self._exes.get(key)
+            if exe is None:
+                avals = jax.tree.map(
+                    lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                       sharding=sh),
+                    avals, self._shardings)
+                exe = self._exes[key] = self._fn.lower(*avals).compile()
+        return exe
+
+    def _key(self, key: tuple) -> tuple:
+        return (self._name, self._d) + key
+
+    def warm(self, key: tuple, avals) -> str | None:
+        """Compile the program for ``avals`` before serving; returns its
+        name, or None when it was already compiled."""
+        from ..ops import backend as _backend  # lazy: no import cycle
+
+        if key in self._exes:
+            return None
+        self._exe(key, avals)
+        name = "/".join(str(k) for k in self._key(key))
+        _backend._mark_seen(self._key(key))
+        return name
+
+    def __call__(self, key: tuple, *args):
+        from ..ops import backend as _backend  # lazy: no import cycle
+
+        _backend._jit_first_sight(*self._key(key))
+        args = jax.device_put(args, self._shardings)
+        return self._exe(key, args)(*args)
+
+
+def _aval(shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _point_aval(n: int):
+    return tuple(_aval((curve.NLIMBS, n)) for _ in range(4))
+
+
 def _point_specs(spec):
     return (spec, spec, spec, spec)
 
@@ -164,33 +228,33 @@ def _row_spec():
 
 
 def make_sharded_verify_each(mesh: Mesh):
-    """Reusable (jit-cached) sharded per-proof checker for ``mesh``.
+    """Reusable (AOT-cached) sharded per-proof checker for ``mesh``.
 
     Returns ``call(g, h, y1, y2, r1, r2, ws, wc) -> [n] bool``; ``g``/``h``
     [20, 1] (replicated), row arrays sharded on the batch axis.  Ragged
     batches are padded to a mesh-size multiple (identity rows with zero
     windows verify True and are sliced off the result).
+    ``call.warm(n)`` compiles the programs an ``n``-lane call dispatches.
     """
     rows = _row_spec()
     rep = P()
-    fn = jax.jit(
-        shard_map(
-            verify.verify_each_kernel,
-            mesh=mesh,
-            in_specs=(
-                _point_specs(rep),
-                _point_specs(rep),
-                _point_specs(rows),
-                _point_specs(rows),
-                _point_specs(rows),
-                _point_specs(rows),
-                rows,
-                rows,
-            ),
-            out_specs=P(AXIS),
-            check_rep=False,
-        )
+    in_specs = (
+        _point_specs(rep),
+        _point_specs(rep),
+        _point_specs(rows),
+        _point_specs(rows),
+        _point_specs(rows),
+        _point_specs(rows),
+        rows,
+        rows,
     )
+    programs = _Programs("mesh_each", mesh, jax.jit(shard_map(
+        verify.verify_each_kernel,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=P(AXIS),
+        check_vma=False,
+    )), in_specs)
     d = mesh.devices.size
 
     def call(g, h, y1, y2, r1, r2, ws, wc):
@@ -198,18 +262,29 @@ def make_sharded_verify_each(mesh: Mesh):
         step, n_to = _mesh_step(d, n)
         y1, y2, r1, r2 = (pad_to_multiple(p, n_to) for p in (y1, y2, r1, r2))
         ws, wc = pad_windows(ws, n_to), pad_windows(wc, n_to)
-        if n_to <= step:
-            return fn(g, h, y1, y2, r1, r2, ws, wc)[:n]
-        chunks = []
-        for lo in range(0, n_to, step):
-            # the last slice may be a short (but d-multiple) remainder
-            hi = min(lo + step, n_to)
-            chunks.append(fn(
-                g, h,
+        # the last slice may be a short (but d-multiple) remainder
+        chunks = [
+            programs(
+                (hi - lo,), g, h,
                 *(tuple(c[..., lo:hi] for c in p) for p in (y1, y2, r1, r2)),
-                ws[:, lo:hi], wc[:, lo:hi]))
-        return jnp.concatenate(chunks, axis=-1)[:n]
+                ws[:, lo:hi], wc[:, lo:hi])
+            for lo, hi in _slices(step, n_to)
+        ]
+        mask = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks)
+        return mask[:n]
 
+    def warm(n: int) -> list[str]:
+        step, n_to = _mesh_pad(d, n)
+        names = []
+        for lo, hi in _slices(step, n_to):
+            w = hi - lo
+            names.append(programs.warm((w,), (
+                _point_aval(1), _point_aval(1),
+                *(_point_aval(w) for _ in range(4)),
+                _aval((curve.NWINDOWS, w)), _aval((curve.NWINDOWS, w)))))
+        return [x for x in names if x]
+
+    call.warm = warm
     return call
 
 
@@ -238,7 +313,7 @@ def make_sharded_prove(mesh: Mesh):
             mesh=mesh,
             in_specs=(_point_specs(P()), _point_specs(P()), rows),
             out_specs=(rows, rows),
-            check_rep=False,
+            check_vma=False,
         )
     )
     d = mesh.devices.size
@@ -304,7 +379,7 @@ def make_sharded_combined_check(mesh: Mesh):
             rows,
         ),
         out_specs=_point_specs(P(None, AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def check(*args):
@@ -332,8 +407,16 @@ def sharded_combined_check(mesh: Mesh, r1, y1, r2, y2, w_a, w_ac, w_ba, w_bac):
     return make_sharded_combined_check(mesh)(r1, y1, r2, y2, w_a, w_ac, w_ba, w_bac)
 
 
+def _reduce_partials(parts):
+    """Mesh-slice [20, D] partial points -> does their sum hit the
+    identity coset (one program: concatenate, tree-sum, test)."""
+    from ..ops import backend as _backend  # lazy: no import cycle
+
+    return _backend._partials_impl(_backend._stack_partials(list(parts)))
+
+
 def make_sharded_msm_check(mesh: Mesh):
-    """Reusable sharded Pippenger-MSM == identity checker for ``mesh``.
+    """Reusable (AOT-cached) sharded Pippenger-MSM == identity checker.
 
     An MSM is a sum over (point, scalar) terms, so lane-sharding is exact:
     each device runs the full windowed-Pippenger kernel on its shard of the
@@ -341,49 +424,68 @@ def make_sharded_msm_check(mesh: Mesh):
     the ``D`` partials combine with one tiny cross-device gather — the ICI
     traffic is 4 coords x 20 limbs per device per batch, nothing else.
 
-    Returns ``call(points, digits, c) -> scalar bool`` (``c`` static per
-    compiled variant, cached by window size).
+    Returns ``call(points, digits, c) -> scalar bool``;
+    ``call.warm(m, c)`` compiles the programs an ``m``-term call
+    dispatches.
     """
     rows = _row_spec()
     d = mesh.devices.size
-    cache: dict[int, object] = {}
+    slice_programs: dict[int, _Programs] = {}  # by window size c
+    reduce_programs: dict[int, _Programs] = {}  # by slice count
 
-    def build(c: int):
-        def partial(points, digits):
-            return msm.msm_kernel(points, digits, c)  # [20, 1] per device
+    def slice_program(c: int) -> _Programs:
+        if c not in slice_programs:
+            def partial(points, digits):
+                return msm.msm_kernel(points, digits, c)  # [20, 1] per device
 
-        fn = shard_map(
-            partial,
-            mesh=mesh,
-            in_specs=(_point_specs(rows), rows),
-            out_specs=_point_specs(P(None, AXIS)),
-            check_rep=False,
-        )
-        return jax.jit(fn)  # (points, digits) -> [20, D] partial points
+            in_specs = (_point_specs(rows), rows)
+            slice_programs[c] = _Programs(f"mesh_msm/{c}", mesh, jax.jit(
+                shard_map(
+                    partial,
+                    mesh=mesh,
+                    in_specs=in_specs,
+                    out_specs=_point_specs(P(None, AXIS)),
+                    check_vma=False,
+                )), in_specs)  # (points, digits) -> [20, D] partials
+        return slice_programs[c]
+
+    def reduce_program(k: int) -> _Programs:
+        if k not in reduce_programs:
+            in_specs = tuple(_point_specs(rows) for _ in range(k))
+            reduce_programs[k] = _Programs(
+                "mesh_partials", mesh,
+                jax.jit(lambda *parts: _reduce_partials(parts)), in_specs)
+        return reduce_programs[k]
 
     def call(points, digits, c: int):
-        from ..ops import backend as _backend  # lazy: no import cycle
-
         m = digits.shape[-1]
         # over-cap MSMs run as mesh slices whose [20, D] partials
         # concatenate into one final tree-sum + identity test
         step, m_to = _mesh_step(d, m)
         points = pad_to_multiple(points, m_to)
         digits = pad_windows(digits, m_to)
-        if c not in cache:
-            cache[c] = build(c)
-        fn = cache[c]
-        if m_to <= step:
-            partials = fn(points, digits)
-        else:
-            parts = [
-                fn(tuple(cd[..., lo:hi] for cd in points), digits[:, lo:hi])
-                for lo, hi in (
-                    (lo, min(lo + step, m_to)) for lo in range(0, m_to, step))
-            ]
-            partials = _backend._stack_partials(parts)
-        return _backend._partials_are_identity(partials)
+        parts = [
+            slice_program(c)(
+                (hi - lo,), tuple(cd[..., lo:hi] for cd in points),
+                digits[:, lo:hi])
+            for lo, hi in _slices(step, m_to)
+        ]
+        return reduce_program(len(parts))((d * len(parts),), *parts)
 
+    def warm(m: int, c: int) -> list[str]:
+        step, m_to = _mesh_pad(d, m)
+        bounds = _slices(step, m_to)
+        k = msm.num_windows(c)
+        names = [
+            slice_program(c).warm(
+                (hi - lo,), (_point_aval(hi - lo), _aval((k, hi - lo))))
+            for lo, hi in bounds
+        ]
+        names.append(reduce_program(len(bounds)).warm(
+            (d * len(bounds),), tuple(_point_aval(d) for _ in bounds)))
+        return [x for x in names if x]
+
+    call.warm = warm
     return call
 
 

@@ -97,16 +97,26 @@ def build_backend(config):
         return None, None
     import jax
 
+    from .. import jaxrt
     from ..ops.backend import TpuBackend, enable_donation, prewarm_executables
     from ..parallel import resolve_lane_devices
     from ..protocol.batch import CpuBackend, FailoverBackend
     from .batching import DynamicBatcher
 
+    cache_dir = jaxrt.enable_compile_cache()
     # serving rebuilds every kernel input per batch, so donated buffers
     # are safe here (and let XLA reuse device memory across batches);
     # XLA CPU ignores donation and warns per call, so gate it off there
     enable_donation(jax.default_backend() != "cpu")
 
+    # the device this process actually got, stated once before the
+    # prewarm: on a box without a chip ``--backend tpu`` runs XLA on the
+    # CPU, and this line (and /statusz ``device``) is how a caller knows
+    dev = jaxrt.describe()
+    device_text = (
+        f"platform={dev['platform']} kind={dev['kind']!r} "
+        f"count={dev['count']} native={dev['native']} cache={cache_dir}"
+    )
     quanta = config.tpu.parsed_prewarm_quanta()
     recovery_after_s = (
         None if config.tpu.recovery_after_s == -1
@@ -116,6 +126,20 @@ def build_backend(config):
     if lane_devices is not None:
         from .router import LaneRouter
 
+        # the resolved topology, surfaced once at boot: lane count +
+        # device list + mesh crossover (and the tpu.lanes gauge for
+        # dashboards that can't read logs)
+        metrics.gauge("tpu.lanes").set(len(lane_devices))
+        log.info(
+            "serving plane: %d per-device dispatch lanes over %s (of %d "
+            "local / %d visible devices), mesh path %s; %s",
+            len(lane_devices),
+            ", ".join(str(d) for d in lane_devices),
+            jax.local_device_count(), jax.device_count(),
+            f"at >= {config.tpu.mesh_threshold} entries"
+            if config.tpu.mesh_threshold > 0 else "off",
+            device_text,
+        )
         lane_backends = [TpuBackend(device=d) for d in lane_devices]
         if quanta:
             t0 = time.monotonic()
@@ -128,6 +152,8 @@ def build_backend(config):
         mesh_backend = None
         if config.tpu.mesh_threshold > 0:
             mesh_backend = TpuBackend(mesh_devices=len(lane_devices))
+            mesh_backend.prewarm(
+                [q for q in quanta if q >= config.tpu.mesh_threshold])
         router = LaneRouter(
             lane_backends,
             devices=lane_devices,
@@ -136,19 +162,6 @@ def build_backend(config):
             recovery_after_s=recovery_after_s,
             mesh_backend=mesh_backend,
             mesh_threshold=config.tpu.mesh_threshold,
-        )
-        # the resolved topology, surfaced once at boot: lane count +
-        # device list + mesh crossover (and the tpu.lanes gauge for
-        # dashboards that can't read logs)
-        metrics.gauge("tpu.lanes").set(len(lane_devices))
-        log.info(
-            "serving plane: %d per-device dispatch lanes over %s (of %d "
-            "local / %d visible devices), mesh path %s",
-            len(lane_devices),
-            ", ".join(str(d) for d in lane_devices),
-            jax.local_device_count(), jax.device_count(),
-            f"at >= {config.tpu.mesh_threshold} entries"
-            if config.tpu.mesh_threshold > 0 else "off",
         )
         batcher = DynamicBatcher(
             lane_backends[0],
@@ -164,27 +177,28 @@ def build_backend(config):
     # k = first k devices; TpuBackend skips the mesh when only 1 is visible.
     # recovery_after_s = -1 disables the breaker's self-healing (degrade
     # until an operator reset), anything else is the probe cooldown.
+    metrics.gauge("tpu.lanes").set(1)
+    log.info(
+        "serving plane: single dispatch lane (%d local / %d visible "
+        "devices; mesh_devices=%d for in-batch sharding); %s",
+        jax.local_device_count(), jax.device_count(),
+        config.tpu.mesh_devices, device_text,
+    )
+    tpu = TpuBackend(mesh_devices=config.tpu.mesh_devices)
     backend = FailoverBackend(
-        TpuBackend(mesh_devices=config.tpu.mesh_devices),
+        tpu,
         CpuBackend(),
         recovery_after_s=recovery_after_s,
         probe_batch_max=config.tpu.probe_batch_max,
     )
     if quanta:
         t0 = time.monotonic()
-        warmed = prewarm_executables(quanta)
+        warmed = tpu.prewarm(quanta)
         log.info(
             "prewarmed %d verify executables for batch quanta %s in %.1fs "
             "(%s)", len(warmed), quanta, time.monotonic() - t0,
             ", ".join(warmed) or "all cached",
         )
-    metrics.gauge("tpu.lanes").set(1)
-    log.info(
-        "serving plane: single dispatch lane (%d local / %d visible "
-        "devices; mesh_devices=%d for in-batch sharding)",
-        jax.local_device_count(), jax.device_count(),
-        config.tpu.mesh_devices,
-    )
     batcher = DynamicBatcher(
         backend,
         max_batch=config.tpu.batch_max,
@@ -193,6 +207,19 @@ def build_backend(config):
         shed_expired=config.tpu.shed_expired,
     )
     return backend, batcher
+
+
+def device_status(config) -> dict:
+    """The ``/statusz`` ``device`` block: the jax device statement plus
+    per-device allocator bytes on the TPU backend; only the native-core
+    flag on the inline CPU path (which never touches jax)."""
+    if config.tpu.backend != "tpu":
+        from ..core import _native
+
+        return {"platform": None, "native": _native.load() is not None}
+    from .. import jaxrt
+
+    return {**jaxrt.describe(), "memory": jaxrt.memory()}
 
 
 async def cleanup_supervisor(
@@ -841,6 +868,7 @@ async def amain(args) -> None:
         durability=durability,
         slo=slo_engine,
         fleet=fleet_router,
+        device=lambda: device_status(config),
         config_fingerprint=config.fingerprint(),
         role="standby" if replica is not None else "server",
     )

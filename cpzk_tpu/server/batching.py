@@ -389,7 +389,11 @@ class DynamicBatcher:
                     break
 
             if self._inflight is None:
-                self._inflight = asyncio.Semaphore(self.pipeline_depth)
+                # pipeline_depth is per dispatch lane: a global bound of
+                # pipeline_depth batches kept all but that many chips of
+                # a multi-lane host idle
+                lanes = self.router.lane_count if self.router is not None else 1
+                self._inflight = asyncio.Semaphore(self.pipeline_depth * lanes)
             while self._queue:
                 # shed entries whose RPC deadline already passed — nobody
                 # is waiting, so device time on them is pure waste

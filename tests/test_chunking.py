@@ -2,7 +2,7 @@
 
 On TPU v5 lite, monolithic device programs past ~33k lanes miscompile:
 deterministic wrong MSM output at m>=40,962, an internal XLA error at
-49,154, all-zero output buffers at 57,346 (benches/debug_pip16k.py),
+49,154, all-zero output buffers at 57,346 (the round-5 on-chip sweep),
 and the per-row combined kernel fails its in-kernel check at 65,538
 rows.  The backend therefore tiles large batches into ``LANE_CHUNK``-lane
 programs and adds partial points (``ops/backend.py``).
@@ -42,6 +42,17 @@ def test_pad_lanes_schedule(tiny_chunks):
     assert _pad_lanes(9) == 16
     assert _pad_lanes(17) == 24
     assert _pad_lanes(24) == 24
+
+
+@pytest.mark.parametrize("n, m", [
+    (1, 6), (2, 10), (3, 16), (5, 32), (8, 34), (20, 128), (36855, 262144),
+])
+def test_msm_shape_whole_tiles(n, m):
+    """The combined MSM's 4n+2 terms fill 4 * pow2(n) slots unless n is
+    a power of two: whole tiles, no near-empty remainder program."""
+    c, m_pad = backend_mod._msm_shape(n)
+    assert m_pad == m >= 4 * n + 2
+    assert c == backend_mod.msm.pick_window(min(m, backend_mod.LANE_CHUNK))
 
 
 def test_chunked_rowcombined_accepts_valid_batch(tiny_chunks):

@@ -519,6 +519,54 @@ def test_mesh_remainder_slice_matches_oracle(monkeypatch):
     assert metrics.read("tpu.batch.occupancy", "g") == pytest.approx(72 / 80)
 
 
+def test_mesh_prewarm_books_hits(monkeypatch):
+    """``TpuBackend.prewarm`` under a mesh compiles the sharded MSM, its
+    partials reduction and the sharded verify_each before serving: a
+    mixed batch at the warmed size runs only warmed programs (every jit
+    check a HIT) and matches the host oracle."""
+    import jax
+
+    from cpzk_tpu import Statement
+    from cpzk_tpu.observability import flightrec
+
+    if jax.device_count() < 2:
+        pytest.skip("no multi-device mesh available")
+    monkeypatch.setattr(backend_mod, "LANE_CHUNK", 8)
+    monkeypatch.setattr(backend_mod, "LANE_QUANTUM", 2)
+    be = TpuBackend(mesh_devices=0)
+    if be._mesh is None:
+        pytest.skip("no multi-device mesh available")
+    d = be._mesh.devices.size
+    warmed = be.prewarm([12])
+    c, m_pad = backend_mod._msm_shape(12)
+    # whole d * LANE_CHUNK-lane MSM slices; 12 rows pad to a d-multiple
+    assert warmed == [f"mesh_msm/{c}/{d}/{d * 8}",
+                      f"mesh_partials/{d}/{m_pad // 8}",
+                      f"mesh_each/{d}/{-(-12 // d) * d}"]
+    assert be.prewarm([12]) == []  # idempotent
+
+    seen = []
+    monkeypatch.setattr(flightrec, "note_jit",
+                        lambda shape, first: seen.append((shape, first)))
+    from test_tpu_backend import make_entries
+
+    entries = make_entries(12)
+    params = entries[4][0]
+    entries[4] = (params, Statement.from_witness(
+        params, Witness(Ristretto255.random_scalar(SecureRng()))),
+        entries[4][2])
+
+    def _run(backend):
+        bv = BatchVerifier(backend=backend)
+        for p, st, pr in entries:
+            bv.add(p, st, pr)
+        return [e is None for e in bv.verify(SecureRng())]
+
+    assert _run(be) == _run(CpuBackend()) == [i != 4 for i in range(12)]
+    assert {shape for shape, _ in seen} == set(warmed)
+    assert not any(first for _, first in seen)
+
+
 # --- satellite: LRU-bounded generator-pair cache ----------------------------
 
 

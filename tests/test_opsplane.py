@@ -843,3 +843,12 @@ def test_config_fingerprint_stable_and_sensitive():
     assert len(a.fingerprint()) == 12
     b.opsplane.port = 9193
     assert a.fingerprint() != b.fingerprint()
+
+
+def test_statusz_device_block():
+    """``/statusz`` carries the daemon's device statement (null when no
+    provider is attached, e.g. the audit pipeline)."""
+    assert OpsSources().statusz()["device"] is None
+    stated = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+              "native": True, "memory": []}
+    assert OpsSources(device=lambda: stated).statusz()["device"] == stated

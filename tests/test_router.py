@@ -126,6 +126,51 @@ def test_router_serves_across_all_lanes():
     assert lanes_seen == {0, 1, 2}
 
 
+def test_pipeline_depth_is_per_lane():
+    """``pipeline_depth`` bounds in-flight batches PER lane: with depth 1
+    and 3 lanes, three batches run at once (a global bound of 1 kept two
+    of three chips idle)."""
+    import threading
+    import time
+
+    lock = threading.Lock()
+    active = [0, 0]  # now, peak
+
+    class Slow(CpuBackend):
+        def _busy(self):
+            with lock:
+                active[0] += 1
+                active[1] = max(active[1], active[0])
+            time.sleep(0.2)
+            with lock:
+                active[0] -= 1
+
+        def verify_combined(self, rows, beta):
+            self._busy()
+            return super().verify_combined(rows, beta)
+
+        def verify_each(self, rows):
+            self._busy()
+            return super().verify_each(rows)
+
+    router = LaneRouter([Slow() for _ in range(3)])
+
+    async def main():
+        batcher = DynamicBatcher(
+            CpuBackend(), max_batch=4, window_ms=1.0, max_queue=10_000,
+            pipeline_depth=1, router=router,
+        )
+        batcher.start()
+        results = await asyncio.gather(
+            *[batcher.submit_many(make_entries(4)) for _ in range(3)]
+        )
+        await batcher.stop()
+        return results
+
+    assert run(main()) == [[None] * 4] * 3
+    assert active[1] == 3
+
+
 # --- per-lane breaker --------------------------------------------------------
 
 
