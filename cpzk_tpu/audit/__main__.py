@@ -86,9 +86,10 @@ def cmd_run(args) -> int:
     t0 = time.monotonic()
 
     # optional ops plane: a long bulk replay is a fleet workload too —
-    # expose /metrics (audit.* counters incl. the proof-log families),
-    # /healthz, and the ring dumps on a daemon-thread HTTP server while
-    # the synchronous pipeline runs
+    # expose /metrics (audit.records among them), /healthz, and the ring
+    # dumps (/tracez: one audit.run trace per run; /flightrec: one record
+    # per quantum) on a daemon-thread HTTP server while the synchronous
+    # pipeline runs
     ops_plane = None
     if args.opsplane_port is not None:
         from ..observability.opsplane import OpsPlane, OpsSources
@@ -100,7 +101,7 @@ def cmd_run(args) -> int:
         bound = ops_plane.start_in_thread()
         print(
             f"# ops plane on http://{args.opsplane_host}:{bound} "
-            "(/metrics /healthz /statusz)",
+            "(/metrics /healthz /statusz /tracez /flightrec)",
             file=sys.stderr, flush=True,
         )
 

@@ -36,6 +36,21 @@ Audit semantics per record:
 - otherwise the batch engine decides: **verified** or **rejected**; a
   computed verdict that contradicts the recorded one increments
   **mismatched** (the number an auditor actually cares about).
+
+Tracing: each :func:`run_audit` call is one ``audit.run`` trace in the
+process's tracer (``/tracez``), finished ``complete``, ``checkpointed``
+(``max_batches`` stopped it) or ``failure``.  Its spans: ``audit.open``
+(cursor, log read, scan, backend build), per quantum ``audit.decode``,
+``audit.parse``, the dispatch seam's ``BatchStages`` spans
+(``pad_and_pack``, ``device_dispatch`` with ``marshal``/``compile``/
+``execute``, ``unpack``), ``audit.fold`` and ``audit.checkpoint`` under
+an ``audit.quantum`` parent, then ``audit.report``.  Every stage span is
+also a ``cpzk.<name>`` profiler annotation; the parents are not, so no
+annotation encloses another.  Each single-engine dispatch books one
+flight record (``/flightrec``; ``lanes != 1`` replays through the router,
+whose dispatch is neither spanned nor recorded), and
+``audit.records{outcome}`` counts records once per quantum.  None of it
+touches a verdict, the fold order or the report.
 """
 
 from __future__ import annotations
@@ -44,12 +59,17 @@ import hashlib
 import json
 import os
 import tempfile
+import time
 
 from .. import errors
 from ..core.ristretto import Ristretto255
 from ..core.rng import SecureRng
+from ..observability.context import RequestContext, new_trace_id
+from ..observability.tracing import BatchStages, get_tracer
 from ..protocol.batch import BatchEntry, BatchVerifier
 from ..protocol.gadgets import Parameters, Proof, Statement
+from ..server import metrics
+from ..server.dispatch import DispatchLane
 from .log import scan_records, validate_proof_record
 from .sign import load_or_create_key, sign_report
 
@@ -336,66 +356,88 @@ def run_audit(
         raise ValueError("audit quantum must be positive")
     cursor_path = cursor_path or report_path + ".cursor"
     key_path = key_path or report_path + ".key"
-    state = AuditState()
-    if resume and os.path.exists(cursor_path):
-        with open(cursor_path, encoding="utf-8") as f:
-            state = AuditState.from_cursor(json.load(f), log_path)
-
-    buf = _read_log_bytes(log_path)
-    if state.offset > len(buf):
-        raise ValueError(
-            f"cursor offset {state.offset} is beyond the log "
-            f"({len(buf)} bytes) — wrong log file?"
-        )
-
-    router = build_router(backend, lanes, quantum)
-    engine = None if router is not None else build_backend(
-        backend, mesh_devices=mesh_devices
-    )
-    rng = SecureRng()
-    # ONE scan of the remaining suffix (the parse cost is linear in what
-    # is left, not quadratic in batch count); quanta then slice the
-    # parsed records, with the cursor offset advanced frame-wise
-    records, valid = scan_records(
-        buf, offset=state.offset, prev_seq=state.prev_seq
-    )
-    batches = 0
-    idx = 0
-    if router is not None:
-        router.start_in_thread()
+    tracer = get_tracer()
+    trace_id = new_trace_id()
+    tracer.start(RequestContext(trace_id=trace_id), "audit.run")
+    status = "failure"
     try:
-        while idx < len(records):
-            batch = records[idx: idx + quantum]
-            idx += len(batch)
-            _audit_batch(batch, state, engine, rng, router=router)
-            state.offset = _advance(buf, state.offset, len(batch))
-            batches += 1
-            _atomic_write_json(cursor_path, state.to_cursor(log_path))
-            if progress is not None:
-                progress(state)
-            if (
-                max_batches is not None and batches >= max_batches
-                and idx < len(records)
-            ):
-                return None
+        with tracer.span(trace_id, "audit.open") as attrs:
+            state = AuditState()
+            if resume and os.path.exists(cursor_path):
+                with open(cursor_path, encoding="utf-8") as f:
+                    state = AuditState.from_cursor(json.load(f), log_path)
+
+            buf = _read_log_bytes(log_path)
+            if state.offset > len(buf):
+                raise ValueError(
+                    f"cursor offset {state.offset} is beyond the log "
+                    f"({len(buf)} bytes) — wrong log file?"
+                )
+
+            router = build_router(backend, lanes, quantum)
+            engine = None if router is not None else build_backend(
+                backend, mesh_devices=mesh_devices
+            )
+            # ONE scan of the remaining suffix (the parse cost is linear in
+            # what is left, not quadratic in batch count); quanta then slice
+            # the parsed records, with the cursor offset advanced frame-wise
+            records, valid = scan_records(
+                buf, offset=state.offset, prev_seq=state.prev_seq
+            )
+            attrs["records"] = len(records)
+            if router is not None:
+                router.start_in_thread()
+        rng = SecureRng()
+        batches = 0
+        idx = 0
+        try:
+            while idx < len(records):
+                batch = records[idx: idx + quantum]
+                idx += len(batch)
+                # the quantum's self time (no stage covers it) is the
+                # caller's progress callback and the loop itself
+                with tracer.span(trace_id, "audit.quantum", annotate=False,
+                                 quantum=batches, records=len(batch)) as attrs:
+                    audited = state.audited
+                    _audit_batch(batch, state, engine, rng, router,
+                                 trace_id, batches, backend)
+                    attrs["settled"] = state.audited - audited
+                    with tracer.span(trace_id, "audit.checkpoint",
+                                     quantum=batches, records=len(batch)):
+                        state.offset = _advance(buf, state.offset, len(batch))
+                        _atomic_write_json(cursor_path, state.to_cursor(log_path))
+                    batches += 1
+                    if progress is not None:
+                        progress(state)
+                if (
+                    max_batches is not None and batches >= max_batches
+                    and idx < len(records)
+                ):
+                    status = "checkpointed"
+                    return None
+        finally:
+            if router is not None:
+                router.stop_thread()
+
+        with tracer.span(trace_id, "audit.report", records=state.records):
+            state.offset = max(state.offset, valid)
+            report = _build_report(
+                log_path, state, valid_bytes=state.offset,
+                file_bytes=len(buf), backend=backend, quantum=quantum,
+            )
+            sign_report(report, load_or_create_key(key_path))
+            _atomic_write_json(report_path, report)
+            # the run is complete: the cursor has served its purpose
+            # (keeping it would make a LATER run against an appended-to
+            # log resume silently)
+            try:
+                os.unlink(cursor_path)
+            except OSError:
+                pass
+        status = "complete"
+        return report
     finally:
-        if router is not None:
-            router.stop_thread()
-    state.offset = max(state.offset, valid)
-
-    report = _build_report(
-        log_path, state, valid_bytes=state.offset,
-        file_bytes=len(buf), backend=backend, quantum=quantum,
-    )
-    sign_report(report, load_or_create_key(key_path))
-    _atomic_write_json(report_path, report)
-    # the run is complete: the cursor has served its purpose (keeping it
-    # would make a LATER run against an appended-to log resume silently)
-    try:
-        os.unlink(cursor_path)
-    except OSError:
-        pass
-    return report
+        tracer.finish(trace_id, status)
 
 
 def _advance(buf: bytes, offset: int, n_frames: int) -> int:
@@ -411,59 +453,77 @@ def _advance(buf: bytes, offset: int, n_frames: int) -> int:
 
 
 def _audit_batch(
-    records: list[dict], state: AuditState, engine, rng, router=None
+    records: list[dict], state: AuditState, engine, rng, router,
+    trace_id: str, index: int, backend: str,
 ) -> None:
     """Verify one quantum of records through the serving dispatch seam —
     the direct ``verify_once`` engine, or the lane router's synchronous
     fan-out (``verify_blocking``) — and fold the outcomes into ``state``
-    IN RECORD ORDER (lane placement never reorders the fold)."""
-    from ..server.dispatch import DispatchLane
+    IN RECORD ORDER (lane placement never reorders the fold).  The
+    stages are spans of quantum ``index`` on the ``trace_id`` trace."""
+    tracer = get_tracer()
 
-    entries: list[BatchEntry] = []
-    plan: list[tuple[dict, str | None, bool]] = []  # (rec, skip, parse_fail)
-    wires: list[bytes] = []
-    for rec in records:
-        entry, skip = _record_entry(rec)
-        if skip is not None:
-            plan.append((rec, skip, False))
-            continue
-        wires.append(bytes.fromhex(rec["p"]))
-        entries.append(entry)
-        plan.append((rec, None, False))
-    # bulk proof parse (deferred point decodes settle inside the batch
-    # engine with exact eager-parse semantics, like the serving path)
-    parsed = Proof.from_bytes_batch(wires, defer_point_validation=True)
-    live: list[BatchEntry] = []
-    k = 0
-    for i, (rec, skip, _) in enumerate(plan):
-        if skip is not None:
-            continue
-        proof = parsed[k]
-        entry = entries[k]
-        k += 1
-        if isinstance(proof, errors.Error):
-            plan[i] = (rec, None, True)  # malformed proof -> rejected
-            continue
-        entry.proof = proof
-        live.append(entry)
+    def span(name: str):
+        return tracer.span(trace_id, name, quantum=index, records=len(records))
+
+    with span("audit.decode"):
+        entries: list[BatchEntry] = []
+        plan: list[tuple[dict, str | None, bool]] = []  # (rec, skip, parse_fail)
+        wires: list[bytes] = []
+        for rec in records:
+            entry, skip = _record_entry(rec)
+            if skip is not None:
+                plan.append((rec, skip, False))
+                continue
+            wires.append(bytes.fromhex(rec["p"]))
+            entries.append(entry)
+            plan.append((rec, None, False))
+    with span("audit.parse"):
+        # bulk proof parse (deferred point decodes settle inside the batch
+        # engine with exact eager-parse semantics, like the serving path)
+        parsed = Proof.from_bytes_batch(wires, defer_point_validation=True)
+        live: list[BatchEntry] = []
+        k = 0
+        for i, (rec, skip, _) in enumerate(plan):
+            if skip is not None:
+                continue
+            proof = parsed[k]
+            entry = entries[k]
+            k += 1
+            if isinstance(proof, errors.Error):
+                plan[i] = (rec, None, True)  # malformed proof -> rejected
+                continue
+            entry.proof = proof
+            live.append(entry)
     if not live:
         results = []
     elif router is not None:
         results = router.verify_blocking(live)
     else:
-        results = DispatchLane.verify_once(engine, rng, live)
-    it = iter(results)
-    for rec, skip, parse_fail in plan:
-        if skip is not None:
-            state.note(rec, OUTCOME_SKIPPED)
-            continue
-        if parse_fail:
-            computed = False
-        else:
-            computed = next(it) is None
-        outcome = OUTCOME_VERIFIED if computed else OUTCOME_REJECTED
-        mismatch = bool(rec.get("v", 0)) != computed
-        state.note(rec, outcome, mismatch=mismatch)
+        stages = BatchStages(tracer, [trace_id], batch_size=len(live),
+                             backend_label=backend)
+        t0 = time.monotonic()
+        results = DispatchLane.verify_once(engine, rng, live, stages)
+        stages.finalize(time.monotonic() - t0)
+    before = (state.verified, state.rejected, state.skipped)
+    with span("audit.fold"):
+        it = iter(results)
+        for rec, skip, parse_fail in plan:
+            if skip is not None:
+                state.note(rec, OUTCOME_SKIPPED)
+                continue
+            if parse_fail:
+                computed = False
+            else:
+                computed = next(it) is None
+            outcome = OUTCOME_VERIFIED if computed else OUTCOME_REJECTED
+            mismatch = bool(rec.get("v", 0)) != computed
+            state.note(rec, outcome, mismatch=mismatch)
+    counted = metrics.counter("audit.records", labelnames=("outcome",))
+    after = (state.verified, state.rejected, state.skipped)
+    for outcome, b, a in zip(("verified", "rejected", "skipped"), before, after):
+        if a > b:
+            counted.labels(outcome=outcome).inc(a - b)
 
 
 def _build_report(

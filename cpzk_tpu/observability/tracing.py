@@ -15,9 +15,10 @@ keyed by trace id (one active attempt per trace id at a time — a PR-1
 retry reuses the id with a bumped attempt, producing one ring entry per
 attempt).
 
-``TraceAnnotation`` alignment: :class:`BatchStages` wraps each software
-stage in ``jax.profiler.TraceAnnotation("cpzk.<stage>")`` when jax is
-already imported, so an xprof capture (CPZK_XPROF_DIR) shows the exact
+``TraceAnnotation`` alignment: :class:`BatchStages` (and
+:meth:`Tracer.span`, which times the audit pipeline's stages) wraps each
+software stage in ``jax.profiler.TraceAnnotation("cpzk.<stage>")`` when
+jax is already imported, so an xprof capture (CPZK_XPROF_DIR) shows the exact
 same stage names the ring buffer reports — software queue math and device
 HLO sit on one timeline.
 """
@@ -28,7 +29,7 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 
 from ..server import metrics
@@ -207,6 +208,23 @@ class Tracer:
                 if rec is not None:
                     rec.spans.append(SpanRecord(name, start, dur, attrs))
 
+    @contextmanager
+    def span(self, trace_id: str | None, name: str, annotate: bool = True,
+             **attrs):
+        """Time the body as one span of ``trace_id``; yields the span's
+        attrs dict, which the body may extend.  With ``annotate`` the body
+        also runs inside a ``cpzk.<name>`` profiler annotation, so a device
+        trace holds the same interval on its own clock.  Annotate leaf
+        spans only: a trace reduction that gives each idle gap to the
+        annotation overlapping it most would hand every gap to an
+        enclosing one."""
+        t0 = time.monotonic()
+        try:
+            with _trace_annotation(name) if annotate else nullcontext():
+                yield attrs
+        finally:
+            self.add_span(trace_id, name, t0, time.monotonic() - t0, **attrs)
+
     def finish(
         self, trace_id: str, status: str, duration_s: float | None = None
     ) -> TraceRecord | None:
@@ -286,12 +304,7 @@ def _trace_annotation(name: str):
             return jax.profiler.TraceAnnotation(f"cpzk.{name}")
         except Exception:  # pragma: no cover - stub jax without profiler
             pass
-
-    @contextmanager
-    def _null():
-        yield
-
-    return _null()
+    return nullcontext()
 
 
 class BatchStages:

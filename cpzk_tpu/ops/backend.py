@@ -859,7 +859,6 @@ class TpuBackend(VerifierBackend):
 
         # correction row: G in slot r1 with -sum(a s), H in slot y1 with
         # -b sum(a s); identity in the other two slots.
-        debug = os.environ.get("CPZK_BATCH_DEBUG") == "1"
         t0 = time.perf_counter()
         pad = _pad_lanes(n + 1)
         _note_pad_waste(n + 1, pad)
@@ -885,19 +884,8 @@ class TpuBackend(VerifierBackend):
             w_ba = _windows(ba, pad)
             w_bac = _windows(bac, pad)
         _note_marshal(t0)
-
-        if not debug:
-            return chunked_combined_identity(
-                pad, r1, y1, r2, y2, w_a, w_ac, w_ba, w_bac)
-        t1 = time.perf_counter()
-        ok = chunked_combined_identity(
+        return chunked_combined_identity(
             pad, r1, y1, r2, y2, w_a, w_ac, w_ba, w_bac)
-        import sys
-
-        print(f"[backend-debug] n={n} pad={pad} marshal={t1 - t0:.3f}s "
-              f"device={time.perf_counter() - t1:.3f}s",
-              file=sys.stderr, flush=True)
-        return ok
 
     def _combined_pippenger(
         self, rows: list[BatchRow], beta: Scalar, device_rlc: bool

@@ -45,9 +45,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
-import sys
 import threading
-import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -78,10 +76,8 @@ class _LaneWork:
 def _run_instrumented(
     bv: BatchVerifier, prepared: PreparedBatch, stages
 ) -> list[Error | None]:
-    """Backend phase with the optional env-gated instrumentation the
-    worker-thread path always had: an xprof capture around the device
-    dispatch (CPZK_XPROF_DIR) and the stage-decomposition stderr line
-    (CPZK_BATCH_DEBUG=1)."""
+    """Backend phase, with an optional xprof capture around the device
+    dispatch (CPZK_XPROF_DIR)."""
     xprof = os.environ.get("CPZK_XPROF_DIR")
     if xprof:
         # JAX profiler (xprof) trace around the device dispatch — the
@@ -93,13 +89,6 @@ def _run_instrumented(
         with jax.profiler.trace(xprof):
             with jax.profiler.TraceAnnotation("cpzk_batch_verify"):
                 return bv.run_prepared(prepared, stages)
-    if os.environ.get("CPZK_BATCH_DEBUG") == "1":
-        t0 = time.perf_counter()
-        out = bv.run_prepared(prepared, stages)
-        print(f"[batch-debug] n={len(bv.entries)} "
-              f"device_phase={time.perf_counter() - t0:.3f}s",
-              file=sys.stderr, flush=True)
-        return out
     return bv.run_prepared(prepared, stages)
 
 
