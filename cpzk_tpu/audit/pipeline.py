@@ -2,13 +2,26 @@
 
 ``python -m cpzk_tpu.audit run`` turns the serving plane's proof log
 (:mod:`cpzk_tpu.audit.log`) back into TPU-sized work: records stream
-through :class:`~cpzk_tpu.protocol.batch.BatchVerifier` via the SAME
-dispatch seam the serving path uses
-(:meth:`~cpzk_tpu.server.dispatch.DispatchLane.verify_once`) at a full
+through :class:`~cpzk_tpu.protocol.batch.BatchVerifier` split into the
+SAME two phases the serving plane's
+:class:`~cpzk_tpu.server.dispatch.DispatchLane` runs on its two threads
+(``prepare_batch`` on the host, ``run_prepared`` on the device) at a full
 batch quantum per dispatch — and through the
 :mod:`~cpzk_tpu.parallel.mesh`-sharded TPU backend when more than one
 device is visible — then emits a Schnorr-signed report
 (:mod:`cpzk_tpu.audit.sign`) stating what it found.
+
+Overlap (the single engine, ``lanes == 1``): one worker thread per
+:func:`run_audit` call runs a quantum's device phase (marshal, the
+combined check, the per-row fallback, unpack) while the calling thread
+decodes, parses and ``prepare_batch``-es the next quantum; it then waits
+for the worker, submits the prepared quantum, and only then folds,
+checkpoints and reports ``progress`` for the finished one.  At most one
+quantum is on the worker and at most one more is prepared.  The native
+core and JAX's device waits release the GIL, which is what overlaps.
+``max_batches=k`` never prepares quantum k+1; the worker is shut down
+before :func:`run_audit` returns or raises.  The ``lanes != 1`` router
+path has its own concurrency and runs its quanta serially.
 
 Resumability contract (the SIGKILL test pins it exactly):
 
@@ -22,6 +35,10 @@ Resumability contract (the SIGKILL test pins it exactly):
   deterministic (:func:`cpzk_tpu.audit.sign._nonce`), a run that is
   SIGKILLed at ANY point and resumed produces a byte-exact-identical
   signed report to an uninterrupted run.
+- The fold runs in record order on the calling thread; the overlap only
+  moves work in time.  A failure in quantum k — in its host prep or its
+  device phase — is raised with its own type and leaves the cursor after
+  quantum k-1, as a serial loop would.
 
 Audit semantics per record:
 
@@ -42,15 +59,30 @@ process's tracer (``/tracez``), finished ``complete``, ``checkpointed``
 (``max_batches`` stopped it) or ``failure``.  Its spans: ``audit.open``
 (cursor, log read, scan, backend build), per quantum ``audit.decode``,
 ``audit.parse``, the dispatch seam's ``BatchStages`` spans
-(``pad_and_pack``, ``device_dispatch`` with ``marshal``/``compile``/
-``execute``, ``unpack``), ``audit.fold`` and ``audit.checkpoint`` under
-an ``audit.quantum`` parent, then ``audit.report``.  Every stage span is
-also a ``cpzk.<name>`` profiler annotation; the parents are not, so no
-annotation encloses another.  Each single-engine dispatch books one
-flight record (``/flightrec``; ``lanes != 1`` replays through the router,
-whose dispatch is neither spanned nor recorded), and
-``audit.records{outcome}`` counts records once per quantum.  None of it
-touches a verdict, the fold order or the report.
+(``pad_and_pack`` on the caller; ``device_wait``, the prepared quantum's
+dwell until the worker takes it, then ``device_dispatch`` with
+``marshal``/``compile``/``execute`` and ``unpack`` on the worker),
+``audit.wait`` (the caller blocked on the quantum's device phase after
+the next quantum is prepared: long when the worker's chain paces the
+replay, near 0 when the host prep does), ``audit.fold`` and
+``audit.checkpoint``, then ``audit.report``.  The ``audit.*`` spans
+carry ``quantum``/``records`` attrs.  One ``audit.quantum`` parent per
+quantum carries ``quantum``, ``records`` and ``settled``; it runs from
+the end of the previous quantum's ``progress`` call (the first: from the
+loop's start) to the end of its own, so the parents tile the caller's
+timeline and each is the wall time one quantum's settling took.  Under
+overlap quantum N's parent holds quantum N+1's decode, parse and
+``pad_and_pack``, then N's wait, fold, checkpoint and ``progress``; the
+worker's spans run beside the caller's, so the stage spans sum to more
+than the wall, by the overlap.  Every
+stage span is also a ``cpzk.<name>`` profiler annotation; the parents
+and ``audit.wait`` are not, so no annotation encloses another on a
+thread and idle gaps are labelled by the stage holding the device back.
+Each single-engine dispatch books one flight record (``/flightrec``;
+``lanes != 1`` replays through the router, whose dispatch is neither
+spanned nor recorded), and ``audit.records{outcome}`` counts records
+once per quantum.  None of it touches a verdict, the fold order or the
+report.
 """
 
 from __future__ import annotations
@@ -60,16 +92,20 @@ import json
 import os
 import tempfile
 import time
+from collections.abc import Callable
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass
+from functools import partial
 
 from .. import errors
 from ..core.ristretto import Ristretto255
 from ..core.rng import SecureRng
 from ..observability.context import RequestContext, new_trace_id
 from ..observability.tracing import BatchStages, get_tracer
-from ..protocol.batch import BatchEntry, BatchVerifier
+from ..protocol.batch import BatchEntry, BatchVerifier, PreparedBatch
 from ..protocol.gadgets import Parameters, Proof, Statement
 from ..server import metrics
-from ..server.dispatch import DispatchLane
+from ..server.dispatch import _run_instrumented
 from .log import scan_records, validate_proof_record
 from .sign import load_or_create_key, sign_report
 
@@ -388,36 +424,55 @@ def run_audit(
             if router is not None:
                 router.start_in_thread()
         rng = SecureRng()
-        batches = 0
-        idx = 0
+        quanta = [records[lo:lo + quantum]
+                  for lo in range(0, len(records), quantum)]
+        stop = len(quanta)
+        if max_batches is not None:
+            stop = min(stop, max(max_batches, 1))
+
+        def prepare(i: int) -> _Quantum:
+            return _prepare_quantum(quanta[i], i, engine, rng, router,
+                                    trace_id, backend)
+
+        settled_at = time.monotonic()
+
+        def finish(q: _Quantum, results: list) -> None:
+            nonlocal settled_at
+            audited = state.audited
+            _fold_quantum(q, results, state, trace_id)
+            with tracer.span(trace_id, "audit.checkpoint",
+                             quantum=q.index, records=len(q.records)):
+                state.offset = _advance(buf, state.offset, len(q.records))
+                _atomic_write_json(cursor_path, state.to_cursor(log_path))
+            if progress is not None:
+                progress(state)
+            now = time.monotonic()
+            tracer.add_span(trace_id, "audit.quantum", settled_at,
+                            now - settled_at, quantum=q.index,
+                            records=len(q.records),
+                            settled=state.audited - audited)
+            settled_at = now
+
+        # the single engine's device phase runs on one worker thread, so
+        # the caller prepares quantum N+1 while quantum N is on the device;
+        # the router has its own concurrency, and its quanta run serially
+        worker = None if router is not None else ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="cpzk-audit-device")
         try:
-            while idx < len(records):
-                batch = records[idx: idx + quantum]
-                idx += len(batch)
-                # the quantum's self time (no stage covers it) is the
-                # caller's progress callback and the loop itself
-                with tracer.span(trace_id, "audit.quantum", annotate=False,
-                                 quantum=batches, records=len(batch)) as attrs:
-                    audited = state.audited
-                    _audit_batch(batch, state, engine, rng, router,
-                                 trace_id, batches, backend)
-                    attrs["settled"] = state.audited - audited
-                    with tracer.span(trace_id, "audit.checkpoint",
-                                     quantum=batches, records=len(batch)):
-                        state.offset = _advance(buf, state.offset, len(batch))
-                        _atomic_write_json(cursor_path, state.to_cursor(log_path))
-                    batches += 1
-                    if progress is not None:
-                        progress(state)
-                if (
-                    max_batches is not None and batches >= max_batches
-                    and idx < len(records)
-                ):
-                    status = "checkpointed"
-                    return None
+            if worker is None:
+                for i in range(stop):
+                    q = prepare(i)
+                    finish(q, q.device_phase())
+            elif stop:
+                _overlapped(prepare, finish, stop, worker, trace_id)
         finally:
             if router is not None:
                 router.stop_thread()
+            if worker is not None:
+                worker.shutdown(wait=True)
+        if stop < len(quanta):
+            status = "checkpointed"
+            return None
 
         with tracer.span(trace_id, "audit.report", records=state.records):
             state.offset = max(state.offset, valid)
@@ -452,15 +507,67 @@ def _advance(buf: bytes, offset: int, n_frames: int) -> int:
     return off
 
 
-def _audit_batch(
-    records: list[dict], state: AuditState, engine, rng, router,
-    trace_id: str, index: int, backend: str,
-) -> None:
-    """Verify one quantum of records through the serving dispatch seam —
-    the direct ``verify_once`` engine, or the lane router's synchronous
-    fan-out (``verify_blocking``) — and fold the outcomes into ``state``
-    IN RECORD ORDER (lane placement never reorders the fold).  The
-    stages are spans of quantum ``index`` on the ``trace_id`` trace."""
+@dataclass
+class _Quantum:
+    """One quantum between its host prep and its fold."""
+
+    index: int
+    records: list[dict]
+    plan: list[tuple[dict, str | None, bool]]  # (rec, skip, parse_fail)
+    #: the device phase: per-entry results of the live entries, in order
+    device_phase: Callable[[], list]
+
+
+def _overlapped(prepare, finish, stop: int, worker, trace_id: str) -> None:
+    """Quanta ``0 .. stop-1`` with one device phase in flight on
+    ``worker``: submit N, prepare N+1, wait for N, submit N+1, then fold
+    and checkpoint N.  A failure in N+1's host prep is raised once N is
+    checkpointed, a failure in N's device phase before N folds: either
+    way the cursor stands where the serial loop would leave it."""
+    tracer = get_tracer()
+    q = prepare(0)
+    running = worker.submit(q.device_phase)
+    for i in range(stop):
+        nxt = failed = None
+        if i + 1 < stop:
+            try:
+                nxt = prepare(i + 1)
+            except Exception as exc:  # re-raised below, after N's checkpoint
+                failed = exc
+        with tracer.span(trace_id, "audit.wait", annotate=False,
+                         quantum=i, records=len(q.records)):
+            results = running.result()
+        if nxt is not None:
+            running = worker.submit(nxt.device_phase)
+        finish(q, results)
+        if failed is not None:
+            raise failed
+        q = nxt
+
+
+def _device_phase(
+    bv: BatchVerifier, prepared: PreparedBatch, stages: BatchStages,
+    t0: float,
+) -> list:
+    """A quantum's dispatch after its ``prepare_batch``: marshal, the
+    combined check, the per-row fallback and unpack, then its flight
+    record (``t0``: when its ``pad_and_pack`` began)."""
+    stages.mark_device_start()
+    results = _run_instrumented(bv, prepared, stages)
+    stages.finalize(time.monotonic() - t0)
+    return results
+
+
+def _prepare_quantum(
+    records: list[dict], index: int, engine, rng, router,
+    trace_id: str, backend: str,
+) -> _Quantum:
+    """The host prep of one quantum on the calling thread: record decode,
+    bulk proof parse and — for the single engine — ``prepare_batch``, the
+    only use of ``rng``.  Its device phase is the engine's
+    ``run_prepared`` or the lane router's synchronous fan-out
+    (``verify_blocking``).  The stages are spans of quantum ``index`` on
+    the ``trace_id`` trace."""
     tracer = get_tracer()
 
     def span(name: str):
@@ -496,19 +603,31 @@ def _audit_batch(
             entry.proof = proof
             live.append(entry)
     if not live:
-        results = []
+        phase = list  # nothing to dispatch: no results
     elif router is not None:
-        results = router.verify_blocking(live)
+        phase = partial(router.verify_blocking, live)
     else:
         stages = BatchStages(tracer, [trace_id], batch_size=len(live),
                              backend_label=backend)
         t0 = time.monotonic()
-        results = DispatchLane.verify_once(engine, rng, live, stages)
-        stages.finalize(time.monotonic() - t0)
+        bv = BatchVerifier(backend=engine, max_size=len(live))
+        bv.entries.extend(live)
+        prepared = bv.prepare_batch(rng, stages)
+        stages.mark_staged()
+        phase = partial(_device_phase, bv, prepared, stages, t0)
+    return _Quantum(index, records, plan, phase)
+
+
+def _fold_quantum(
+    q: _Quantum, results: list, state: AuditState, trace_id: str,
+) -> None:
+    """Fold a quantum's outcomes into ``state`` IN RECORD ORDER (lane
+    placement and the worker never reorder the fold)."""
     before = (state.verified, state.rejected, state.skipped)
-    with span("audit.fold"):
+    with get_tracer().span(trace_id, "audit.fold", quantum=q.index,
+                           records=len(q.records)):
         it = iter(results)
-        for rec, skip, parse_fail in plan:
+        for rec, skip, parse_fail in q.plan:
             if skip is not None:
                 state.note(rec, OUTCOME_SKIPPED)
                 continue

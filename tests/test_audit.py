@@ -7,12 +7,15 @@ stream paths all append records), and the ``[audit]`` config section
 
 import asyncio
 import dataclasses
+import json
 import os
 import pathlib
 import re
 import signal
+import struct
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -26,10 +29,12 @@ from cpzk_tpu.audit import (
     scan_records,
     verify_report_file,
 )
+from cpzk_tpu.audit import pipeline
 from cpzk_tpu.audit import sign as audit_sign
 from cpzk_tpu.audit.log import validate_proof_record
 from cpzk_tpu.audit.pipeline import AuditState
 from cpzk_tpu.core.ristretto import Ristretto255
+from cpzk_tpu.protocol.batch import CpuBackend
 from cpzk_tpu.server.config import AuditSettings, ServerConfig
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -186,6 +191,117 @@ def test_pipeline_resume_is_byte_exact(tmp_path):
     assert resumed is not None
     assert open(a).read() == open(b).read()  # signature included
     assert resumed["digest"] == full["digest"]
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+class _DeviceFault(CpuBackend):
+    """Raises in the device phase of quantum ``fail_at`` (each quantum
+    holds a wrong secret, so each dispatch reaches ``verify_each``)."""
+
+    def __init__(self, fail_at: int):
+        super().__init__()
+        self.fail_at = fail_at
+        self.calls = 0
+
+    def verify_each(self, rows):
+        self.calls += 1
+        if self.calls == self.fail_at + 1:
+            raise _Boom("device phase")
+        return super().verify_each(rows)
+
+
+def _frame_offsets(path) -> list[int]:
+    """Byte offset after each frame of a proof log, read apart from the
+    pipeline: ``offsets[n]`` is where a cursor stands after n records."""
+    buf = pathlib.Path(path).read_bytes()
+    offsets = [0]
+    while offsets[-1] < len(buf):
+        (length,) = struct.unpack_from(">I", buf, offsets[-1])
+        offsets.append(offsets[-1] + 8 + length)
+    return offsets
+
+
+def _audit_workers() -> list:
+    return [t for t in threading.enumerate()
+            if t.name.startswith("cpzk-audit-device")]
+
+
+@pytest.mark.parametrize("phase", ["device", "host"])
+def test_pipeline_failure_leaves_cursor_after_previous_quantum(
+        tmp_path, monkeypatch, phase):
+    """A failure in quantum k — its device phase on the worker, or its
+    host prep on the caller while k-1 is in flight — raises its own type
+    and leaves the cursor after k-1, as a serial loop does; no worker
+    thread outlives the call, and a resume signs the identical report."""
+    quantum, k = 8, 2
+    log = tmp_path / "p.log"
+    make_log(log, 4 * quantum, reject_every=quantum)
+    key = str(tmp_path / "audit.key")
+    full = str(tmp_path / "full.json")
+    assert run_audit(str(log), full, key_path=key, quantum=quantum)
+
+    if phase == "device":
+        monkeypatch.setattr(pipeline, "build_backend",
+                            lambda *a, **kw: _DeviceFault(k))
+    else:
+        decoded = []
+        real = pipeline._record_entry
+
+        def record_entry(rec):
+            if len(decoded) == k * quantum:
+                raise _Boom("host prep")
+            decoded.append(rec)
+            return real(rec)
+
+        monkeypatch.setattr(pipeline, "_record_entry", record_entry)
+    out = str(tmp_path / "out.json")
+    with pytest.raises(_Boom):
+        run_audit(str(log), out, key_path=key, quantum=quantum)
+    assert not _audit_workers()
+    with open(out + ".cursor", encoding="utf-8") as f:
+        cursor = json.load(f)
+    assert cursor["records"] == k * quantum
+    assert cursor["offset"] == _frame_offsets(log)[k * quantum]
+
+    monkeypatch.undo()
+    assert run_audit(str(log), out, key_path=key, quantum=quantum)
+    assert pathlib.Path(out).read_bytes() == pathlib.Path(full).read_bytes()
+
+
+@pytest.mark.parametrize("max_batches", [1, 2])
+def test_pipeline_max_batches_folds_exactly_that_many(tmp_path, max_batches):
+    """``max_batches=k`` folds and checkpoints k quanta, calls
+    ``progress`` once after each checkpoint, and never prepares or
+    dispatches quantum k+1."""
+    from cpzk_tpu.observability import get_tracer
+
+    quantum = 8
+    log = tmp_path / "p.log"
+    make_log(log, 4 * quantum, reject_every=quantum)
+    offsets = _frame_offsets(log)
+    out = str(tmp_path / "out.json")
+    seen = []
+
+    def progress(state):
+        with open(out + ".cursor", encoding="utf-8") as f:
+            seen.append((state.records, json.load(f)["offset"]))
+
+    assert run_audit(str(log), out, quantum=quantum, max_batches=max_batches,
+                     progress=progress) is None
+    assert not _audit_workers()
+    assert seen == [(n * quantum, offsets[n * quantum])
+                    for n in range(1, max_batches + 1)]
+    trace = [t for t in get_tracer().completed() if t.name == "audit.run"][-1]
+    assert trace.status == "checkpointed"
+    names = trace.span_names()
+    assert names.count("audit.fold") == max_batches
+    assert names.count("pad_and_pack") == max_batches
+    assert names.count("device_dispatch") == max_batches
+    assert "audit.decode" not in [
+        s.name for s in trace.spans if s.attrs.get("quantum") == max_batches]
 
 
 def test_pipeline_skips_garbage_and_stops_at_corruption(tmp_path):

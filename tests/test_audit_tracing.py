@@ -11,17 +11,27 @@ import time
 
 import pytest
 
-from cpzk_tpu.audit import run_audit
+from cpzk_tpu.audit import pipeline, run_audit
 from cpzk_tpu.audit.__main__ import main as audit_main
 from cpzk_tpu.observability import get_flight_recorder, get_tracer, tracing
+from cpzk_tpu.protocol.batch import CpuBackend
 from cpzk_tpu.server import metrics
 
 RECORDS = 64
 QUANTUM = 16
-#: every quantum's children (CpuBackend reports no marshal or compile)
-QUANTUM_STAGES = {
-    "audit.decode", "audit.parse", "pad_and_pack", "device_dispatch",
-    "execute", "unpack", "audit.fold", "audit.checkpoint",
+#: every quantum's own spans, matched by their ``quantum`` attr
+AUDIT_STAGES = {
+    "audit.decode", "audit.parse", "audit.wait", "audit.fold",
+    "audit.checkpoint",
+}
+#: the dispatch seam's spans, one each per quantum, in quantum order
+#: (CpuBackend reports no marshal or compile)
+SEAM_STAGES = {
+    "pad_and_pack", "device_wait", "device_dispatch", "execute", "unpack",
+}
+#: the stages a profiler annotation marks (leaves only)
+ANNOTATED = (AUDIT_STAGES | SEAM_STAGES) - {
+    "audit.wait", "device_wait", "execute",
 }
 OUTCOMES = ("verified", "rejected", "skipped")
 
@@ -93,15 +103,37 @@ def test_audit_run_trace(tmp_path, monkeypatch, proof_log, max_batches):
     assert names.count("audit.report") == (1 if report else 0)
     parents = [s for s in trace.spans if s.name == "audit.quantum"]
     assert [s.attrs["quantum"] for s in parents] == list(range(quanta))
+    seam = {name: [s for s in trace.spans if s.name == name]
+            for name in SEAM_STAGES}
+    assert {name: len(v) for name, v in seam.items()} == dict.fromkeys(
+        SEAM_STAGES, quanta)
+    for prev, q in zip(parents, parents[1:]):  # the parents tile the run
+        assert q.start == pytest.approx(prev.start + prev.duration_s, abs=1e-6)
     for q in parents:
+        index = q.attrs["quantum"]
         assert q.attrs["records"] == QUANTUM and q.attrs["settled"] == QUANTUM
-        children = [s for s in trace.spans if _inside(s, q)]
-        assert {s.name for s in children} == QUANTUM_STAGES, q.attrs
-        for s in children:
-            if s.name.startswith("audit."):
-                assert s.attrs == {"quantum": q.attrs["quantum"],
-                                   "records": QUANTUM}
-        covered = _covered((s.start, s.start + s.duration_s) for s in children)
+        # a quantum's spans are matched by attr (audit.*) or by order (the
+        # seam's), not by time: its host prep runs inside the previous
+        # quantum's parent, while that quantum is on the worker
+        mine = [s for s in trace.spans if s.name in AUDIT_STAGES
+                and s.attrs.get("quantum") == index]
+        assert [s.name for s in mine] == [
+            "audit.decode", "audit.parse", "audit.wait", "audit.fold",
+            "audit.checkpoint"], q.attrs
+        assert all(s.attrs == {"quantum": index, "records": QUANTUM}
+                   for s in mine)
+        own = {s.name: s for s in mine}
+        prep = parents[max(index - 1, 0)]
+        assert all(_inside(s, prep) for s in (
+            own["audit.decode"], own["audit.parse"],
+            seam["pad_and_pack"][index])), q.attrs
+        # (the worker's spans fall where the device phase runs: in either)
+        assert all(_inside(s, q) for s in (
+            own["audit.wait"], own["audit.fold"], own["audit.checkpoint"],
+        )), q.attrs
+        # every instant of the parent is some stage's, on either thread
+        covered = _covered((s.start, s.start + s.duration_s)
+                           for s in trace.spans if _inside(s, q))
         assert covered >= 0.9 * q.duration_s, (covered, q.duration_s)
 
     # one flight record per quantum's dispatch
@@ -120,8 +152,8 @@ def test_audit_run_trace(tmp_path, monkeypatch, proof_log, max_batches):
 
     # leaf annotations only, and none encloses or overlaps another
     annotated = {name for _, name, _, _ in annotations}
-    assert "audit.quantum" not in annotated
-    assert QUANTUM_STAGES - {"execute"} <= annotated
+    assert not {"audit.quantum", "audit.wait"} & annotated
+    assert ANNOTATED <= annotated
     for thread in {a[0] for a in annotations}:
         spans = sorted(a[2:] for a in annotations if a[0] == thread)
         for (_, end), (start, _) in zip(spans, spans[1:]):
@@ -137,4 +169,42 @@ def test_audit_run_trace(tmp_path, monkeypatch, proof_log, max_batches):
     fresh = str(tmp_path / "fresh.json")
     assert run_audit(proof_log, fresh, key_path=key, quantum=QUANTUM)
     with open(report_path, "rb") as a, open(fresh, "rb") as b:
+        assert a.read() == b.read()
+
+
+class _SlowDevice(CpuBackend):
+    """A device phase that waits with the GIL released, as a fetch from
+    the chip does."""
+
+    def verify_each(self, rows):
+        time.sleep(0.05)
+        return super().verify_each(rows)
+
+
+def test_next_quantum_prepares_while_the_device_runs(tmp_path, monkeypatch,
+                                                     proof_log):
+    monkeypatch.setattr(pipeline, "build_backend",
+                        lambda *a, **kw: _SlowDevice())
+    key = str(tmp_path / "audit.key")
+    slow = str(tmp_path / "slow.json")
+    assert run_audit(proof_log, slow, key_path=key, quantum=QUANTUM)
+    quanta = RECORDS // QUANTUM
+
+    (trace,) = [t for t in get_tracer().completed() if t.name == "audit.run"]
+    decode = {s.attrs["quantum"]: s for s in trace.spans
+              if s.name == "audit.decode"}
+    execute = [s for s in trace.spans if s.name == "execute"]
+    assert len(execute) == quanta
+    for n in range(quanta - 1):
+        assert decode[n + 1].start < execute[n].start + execute[n].duration_s, n
+    waits = sorted(s.attrs["quantum"] for s in trace.spans
+                   if s.name == "audit.wait")
+    assert waits == list(range(quanta))
+
+    # the overlap moves work in time only: the stock backend signs the
+    # same bytes
+    monkeypatch.undo()
+    stock = str(tmp_path / "stock.json")
+    assert run_audit(proof_log, stock, key_path=key, quantum=QUANTUM)
+    with open(slow, "rb") as a, open(stock, "rb") as b:
         assert a.read() == b.read()
