@@ -61,7 +61,8 @@ process's tracer (``/tracez``), finished ``complete``, ``checkpointed``
 ``audit.parse``, the dispatch seam's ``BatchStages`` spans
 (``pad_and_pack`` on the caller; ``device_wait``, the prepared quantum's
 dwell until the worker takes it, then ``device_dispatch`` with
-``marshal``/``compile``/``execute`` and ``unpack`` on the worker),
+``marshal``/``compile``/``execute`` — under a mesh also the ``mesh.*``
+spans of :mod:`~cpzk_tpu.parallel.mesh` — and ``unpack`` on the worker),
 ``audit.wait`` (the caller blocked on the quantum's device phase after
 the next quantum is prepared: long when the worker's chain paces the
 replay, near 0 when the host prep does), ``audit.fold`` and
@@ -75,9 +76,10 @@ overlap quantum N's parent holds quantum N+1's decode, parse and
 ``pad_and_pack``, then N's wait, fold, checkpoint and ``progress``; the
 worker's spans run beside the caller's, so the stage spans sum to more
 than the wall, by the overlap.  Every
-stage span is also a ``cpzk.<name>`` profiler annotation; the parents
-and ``audit.wait`` are not, so no annotation encloses another on a
-thread and idle gaps are labelled by the stage holding the device back.
+stage span is also a ``cpzk.<name>`` profiler annotation; the parents,
+``audit.wait`` and the ``mesh.*`` spans are not, so no annotation
+encloses another on a thread and idle gaps are labelled by the stage
+holding the device back.
 Each single-engine dispatch books one flight record (``/flightrec``;
 ``lanes != 1`` replays through the router, whose dispatch is neither
 spanned nor recorded), and ``audit.records{outcome}`` counts records
@@ -277,7 +279,9 @@ def build_backend(backend_name: str, mesh_devices: int = 0):
     """The audit compute plane: the CPU oracle, or the mesh-sharded TPU
     backend (``mesh_devices`` semantics shared with serving: 0 = all
     visible devices — :func:`cpzk_tpu.parallel.mesh.resolve_mesh_devices`
-    decides whether a real mesh is built)."""
+    decides whether a real mesh is built).  A mesh's compiled programs
+    are the process's, so the backend each call builds finds what an
+    earlier call or ``TpuBackend.prewarm`` compiled."""
     if backend_name == "tpu":
         from ..ops.backend import TpuBackend
 

@@ -35,6 +35,7 @@ window mutation is lock-guarded.
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import json
 import logging
@@ -94,6 +95,9 @@ class DeviceSink:
     rows: int = 0
     lanes: int = 0
     combined: bool | None = None
+    #: (name, start, seconds) of the backend's own sub-spans
+    #: (:func:`device_span`), in the order they ended
+    spans: list[tuple[str, float, float]] = field(default_factory=list)
 
 
 _SINK: contextvars.ContextVar[DeviceSink | None] = contextvars.ContextVar(
@@ -115,6 +119,21 @@ def note_marshal(duration_s: float) -> None:
     sink = _SINK.get()
     if sink is not None:
         sink.marshal_s += max(0.0, duration_s)
+
+
+@contextlib.contextmanager
+def device_span(name: str):
+    """Time the body as a sub-span of the current device dispatch (the
+    stage recorder attaches it to the batch's traces beside ``marshal``
+    and ``execute``).  No profiler annotation: ``device_dispatch`` holds
+    one already.  Outside an instrumented dispatch it records nothing."""
+    t0 = time.monotonic()
+    try:
+        yield
+    finally:
+        sink = _SINK.get()
+        if sink is not None:
+            sink.spans.append((name, t0, time.monotonic() - t0))
 
 
 def note_jit(shape: str, first_sight: bool) -> None:

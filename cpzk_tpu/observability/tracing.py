@@ -454,6 +454,13 @@ class BatchStages:
             )
             metrics.histogram("tpu.jit.compile_time").observe(compile_s)
         self._emit(STAGE_EXECUTE, t0 + marshal + compile_s, execute_s)
+        if self.tracer is not None:
+            # the backend's own sub-spans overlap marshal and execute, so
+            # they go to the traces only, not into the record's stage sum
+            for name, start, dur in sink.spans:
+                self.tracer.add_span_many(
+                    self.trace_ids, name, start, dur,
+                    batch=self.batch_size, backend=self.backend_label)
         self._gap_s = flightrec.get_flight_recorder().note_device_interval(
             t0, t0 + dur
         )

@@ -824,7 +824,9 @@ class TpuBackend(VerifierBackend):
         batch sizes before serving: the sharded MSM (with its partials
         reduction) and sharded ``verify_each`` under a mesh, else the
         single-device kernels (:func:`prewarm_executables`).  Returns
-        the warmed program names; their first dispatch books a jit HIT."""
+        the warmed program names; their first dispatch books a jit HIT.
+        The sharded programs are the process's (``parallel.mesh``): a
+        later instance over the same chips finds them and returns none."""
         if self._mesh is None:
             return prewarm_executables(
                 batch_sizes,
@@ -908,30 +910,38 @@ class TpuBackend(VerifierBackend):
             + [r.y2 for r in rows]
             + [rows[0].g, rows[0].h]
         )
+        terms = 4 * len(rows) + 2
         c, m_pad = _msm_shape(len(rows))
-        _note_pad_waste(4 * len(rows) + 2, m_pad)
+        _note_pad_waste(terms, m_pad)
         pts = _elems_soa(elems, m_pad, device=self._device)
-        if device_rlc:
-            digits = _pippenger_digits_device(rows, beta, m_pad, c)
-        else:
-            b = beta.value
-            a = [r.alpha.value for r in rows]
-            ch = [r.c.value for r in rows]
-            s = [r.s.value for r in rows]
-            ac = [x * y % L for x, y in zip(a, ch)]
-            ba = [b * x % L for x in a]
-            bac = [b * x % L for x in ac]
-            sum_as = sum(x * y for x, y in zip(a, s)) % L
-            scalars = a + ac + ba + bac + [
-                (L - sum_as) % L, (L - b * sum_as % L) % L,
-            ]
-            digits = jnp.asarray(
-                msm.scalars_to_signed_digits(
-                    scalars + [0] * (m_pad - len(scalars)), c)
-            )
+        mesh = self._sharded_msm is not None
+        from ..observability import flightrec
+
+        # the scalar products and their signed-digit recode
+        digits_span = (flightrec.device_span("mesh.digits") if mesh
+                       else contextlib.nullcontext())
+        with digits_span:
+            if device_rlc:
+                digits = _pippenger_digits_device(rows, beta, m_pad, c)
+            else:
+                b = beta.value
+                a = [r.alpha.value for r in rows]
+                ch = [r.c.value for r in rows]
+                s = [r.s.value for r in rows]
+                ac = [x * y % L for x, y in zip(a, ch)]
+                ba = [b * x % L for x in a]
+                bac = [b * x % L for x in ac]
+                sum_as = sum(x * y for x, y in zip(a, s)) % L
+                scalars = a + ac + ba + bac + [
+                    (L - sum_as) % L, (L - b * sum_as % L) % L,
+                ]
+                digits = jnp.asarray(
+                    msm.scalars_to_signed_digits(
+                        scalars + [0] * (m_pad - len(scalars)), c)
+                )
         _note_marshal(t0)
-        if self._sharded_msm is not None:
-            return bool(self._sharded_msm(pts, digits, c))
+        if mesh:
+            return self._sharded_msm(pts, digits, c, real=terms)
         return chunked_msm_identity(c, pts, digits)
 
     def verify_each(self, rows: list[BatchRow]) -> list[bool]:
@@ -959,7 +969,7 @@ class TpuBackend(VerifierBackend):
         _note_marshal(t0)
 
         if self._sharded_each is not None and shared:
-            mask = self._sharded_each(g, h, y1, y2, r1, r2, ws, wc)
+            mask = self._sharded_each(g, h, y1, y2, r1, r2, ws, wc, real=n)
         elif pad > LANE_CHUNK:
             # per-row checks are lane-independent: tile and concatenate
             chunks = []
@@ -976,10 +986,4 @@ class TpuBackend(VerifierBackend):
         else:
             _jit_first_sight("each", pad, shared)
             mask = _each_shared(pad, g, h, y1, y2, r1, r2, ws, wc)
-        if hasattr(mask, "is_fully_addressable") and not mask.is_fully_addressable:
-            # multi-host job: the [n]-sharded result spans devices owned by
-            # other processes; gather the global value everywhere
-            from jax.experimental import multihost_utils
-
-            mask = multihost_utils.process_allgather(mask, tiled=True)
         return [bool(v) for v in np.asarray(mask)[:n]]

@@ -158,56 +158,110 @@ def _note_occupancy(n: int, n_to: int) -> None:
         pass
 
 
+def _span(name: str):
+    """A ``mesh.*`` sub-span of the current device dispatch."""
+    from ..observability import flightrec  # lazy: no import cycle
+
+    return flightrec.device_span(name)
+
+
+def _note_lanes(real: int, n_to: int) -> None:
+    """``mesh.lanes{kind}``: real terms or rows, and identity pad lanes,
+    sent to the mesh programs."""
+    try:
+        from ..server import metrics
+
+        lanes = metrics.counter("mesh.lanes", labelnames=("kind",))
+        lanes.labels(kind="term").inc(real)
+        lanes.labels(kind="pad").inc(n_to - real)
+    except Exception:  # pragma: no cover - server layer unavailable
+        pass
+
+
+def _note_compile(when: str) -> None:
+    """``mesh.compiles{when}``: sharded programs compiled, at ``prewarm``
+    or while ``serving``."""
+    try:
+        from ..server import metrics
+
+        metrics.counter("mesh.compiles", labelnames=("when",)).labels(
+            when=when).inc()
+    except Exception:  # pragma: no cover - server layer unavailable
+        pass
+
+
+#: The process's compiled sharded programs, keyed by (the mesh's device
+#: ids, program name, input shape): every wrapper over the same devices
+#: finds what any other compiled or prewarmed, so a backend built per
+#: audit run (``run_audit``) or per daemon boot compiles nothing twice.
+_EXES: dict[tuple, object] = {}
+#: Guards ``_EXES``; held through a compile, so a program is compiled
+#: once even when pipelined batches dispatch from worker threads.
+_EXES_LOCK = threading.Lock()
+
+
 class _Programs:
     """The AOT-compiled programs of one sharded wrapper, one per input
-    shape, compiled at most once (``jit(...).lower(...).compile()``, the
-    single-device AOT cache's scheme).  Every dispatch is booked with the
-    flight recorder's jit counters under ``(name, d, shape...)``: a
-    program :meth:`warm` compiled before ready is a HIT, one compiled on
-    first sight while serving is a MISS."""
+    shape, each compiled at most once per process
+    (``jit(...).lower(...).compile()``, the single-device AOT cache's
+    scheme, into ``_EXES``).  Every dispatch is booked with the flight
+    recorder's jit counters under ``(name, d, shape...)``: a program
+    :meth:`warm` compiled before ready is a HIT, one compiled on first
+    sight while serving is a MISS."""
 
     def __init__(self, name: str, mesh: Mesh, fn, in_specs):
         self._name = name
         self._d = mesh.devices.size
+        self._ids = tuple(int(dev.id) for dev in mesh.devices.flat)
         self._fn = fn
         self._shardings = jax.tree.map(
             lambda spec: NamedSharding(mesh, spec), in_specs,
             is_leaf=lambda x: isinstance(x, P))
-        self._exes: dict[tuple, object] = {}
-        self._lock = threading.Lock()
 
-    def _exe(self, key: tuple, avals):
-        with self._lock:  # pipelined batches dispatch from worker threads
-            exe = self._exes.get(key)
-            if exe is None:
-                avals = jax.tree.map(
-                    lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                       sharding=sh),
-                    avals, self._shardings)
-                exe = self._exes[key] = self._fn.lower(*avals).compile()
-        return exe
+    def _exe(self, key: tuple, avals, when: str):
+        """(executable, compiled now) for ``avals``."""
+        full = (self._ids, self._name) + key
+        with _EXES_LOCK:
+            exe = _EXES.get(full)
+            if exe is not None:
+                return exe, False
+            avals = jax.tree.map(
+                lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=sh),
+                avals, self._shardings)
+            exe = _EXES[full] = self._fn.lower(*avals).compile()
+        _note_compile(when)
+        return exe, True
 
     def _key(self, key: tuple) -> tuple:
         return (self._name, self._d) + key
 
     def warm(self, key: tuple, avals) -> str | None:
         """Compile the program for ``avals`` before serving; returns its
-        name, or None when it was already compiled."""
+        name, or None when the process already holds it."""
         from ..ops import backend as _backend  # lazy: no import cycle
 
-        if key in self._exes:
-            return None
-        self._exe(key, avals)
-        name = "/".join(str(k) for k in self._key(key))
         _backend._mark_seen(self._key(key))
-        return name
+        if not self._exe(key, avals, "prewarm")[1]:
+            return None
+        return "/".join(str(k) for k in self._key(key))
 
     def __call__(self, key: tuple, *args):
         from ..ops import backend as _backend  # lazy: no import cycle
 
         _backend._jit_first_sight(*self._key(key))
         args = jax.device_put(args, self._shardings)
-        return self._exe(key, args)(*args)
+        return self._exe(key, args, "serving")[0](*args)
+
+
+def _fetch(x) -> np.ndarray:
+    """A result on the host; a multi-host job's [n]-sharded result spans
+    devices other processes own, so it is gathered everywhere first."""
+    if not x.is_fully_addressable:
+        from jax.experimental import multihost_utils
+
+        x = multihost_utils.process_allgather(x, tiled=True)
+    return np.asarray(x)
 
 
 def _aval(shape, dtype=jnp.int32):
@@ -230,10 +284,13 @@ def _row_spec():
 def make_sharded_verify_each(mesh: Mesh):
     """Reusable (AOT-cached) sharded per-proof checker for ``mesh``.
 
-    Returns ``call(g, h, y1, y2, r1, r2, ws, wc) -> [n] bool``; ``g``/``h``
-    [20, 1] (replicated), row arrays sharded on the batch axis.  Ragged
-    batches are padded to a mesh-size multiple (identity rows with zero
-    windows verify True and are sliced off the result).
+    Returns ``call(g, h, y1, y2, r1, r2, ws, wc, real=None) -> [n] bool``
+    (a host array); ``g``/``h`` [20, 1] (replicated), row arrays sharded
+    on the batch axis.  Ragged batches are padded to a mesh-size multiple
+    (identity rows with zero windows verify True and are sliced off the
+    result); ``real`` counts the rows that are not such padding
+    (``mesh.lanes``; default all ``n``).  Span: ``mesh.each`` (the rows
+    placed on the mesh, the programs and the mask's fetch).
     ``call.warm(n)`` compiles the programs an ``n``-lane call dispatches.
     """
     rows = _row_spec()
@@ -248,8 +305,12 @@ def make_sharded_verify_each(mesh: Mesh):
         rows,
         rows,
     )
+
+    def mesh_each(*args):  # the name is the device trace's module name
+        return verify.verify_each_kernel(*args)
+
     programs = _Programs("mesh_each", mesh, jax.jit(shard_map(
-        verify.verify_each_kernel,
+        mesh_each,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(AXIS),
@@ -257,21 +318,24 @@ def make_sharded_verify_each(mesh: Mesh):
     )), in_specs)
     d = mesh.devices.size
 
-    def call(g, h, y1, y2, r1, r2, ws, wc):
+    def call(g, h, y1, y2, r1, r2, ws, wc, real: int | None = None):
         n = ws.shape[-1]
         step, n_to = _mesh_step(d, n)
+        _note_lanes(n if real is None else real, n_to)
         y1, y2, r1, r2 = (pad_to_multiple(p, n_to) for p in (y1, y2, r1, r2))
         ws, wc = pad_windows(ws, n_to), pad_windows(wc, n_to)
         # the last slice may be a short (but d-multiple) remainder
-        chunks = [
-            programs(
-                (hi - lo,), g, h,
-                *(tuple(c[..., lo:hi] for c in p) for p in (y1, y2, r1, r2)),
-                ws[:, lo:hi], wc[:, lo:hi])
-            for lo, hi in _slices(step, n_to)
-        ]
-        mask = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks)
-        return mask[:n]
+        with _span("mesh.each"):
+            chunks = [
+                programs(
+                    (hi - lo,), g, h,
+                    *(tuple(c[..., lo:hi] for c in p)
+                      for p in (y1, y2, r1, r2)),
+                    ws[:, lo:hi], wc[:, lo:hi])
+                for lo, hi in _slices(step, n_to)
+            ]
+            mask = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks)
+            return _fetch(mask)[:n]
 
     def warm(n: int) -> list[str]:
         step, n_to = _mesh_pad(d, n)
@@ -424,9 +488,12 @@ def make_sharded_msm_check(mesh: Mesh):
     the ``D`` partials combine with one tiny cross-device gather — the ICI
     traffic is 4 coords x 20 limbs per device per batch, nothing else.
 
-    Returns ``call(points, digits, c) -> scalar bool``;
-    ``call.warm(m, c)`` compiles the programs an ``m``-term call
-    dispatches.
+    Returns ``call(points, digits, c, real=None) -> bool``; ``real``
+    counts the terms that are not identity padding (``mesh.lanes``;
+    default all ``m``).  Span: ``mesh.msm`` (the terms placed on the
+    mesh, the slice programs, the partials reduction and the verdict's
+    fetch).  ``call.warm(m, c)`` compiles the programs an ``m``-term
+    call dispatches.
     """
     rows = _row_spec()
     d = mesh.devices.size
@@ -435,13 +502,14 @@ def make_sharded_msm_check(mesh: Mesh):
 
     def slice_program(c: int) -> _Programs:
         if c not in slice_programs:
-            def partial(points, digits):
+            # the name is the device trace's module name
+            def mesh_msm_slice(points, digits):
                 return msm.msm_kernel(points, digits, c)  # [20, 1] per device
 
             in_specs = (_point_specs(rows), rows)
             slice_programs[c] = _Programs(f"mesh_msm/{c}", mesh, jax.jit(
                 shard_map(
-                    partial,
+                    mesh_msm_slice,
                     mesh=mesh,
                     in_specs=in_specs,
                     out_specs=_point_specs(P(None, AXIS)),
@@ -451,26 +519,30 @@ def make_sharded_msm_check(mesh: Mesh):
 
     def reduce_program(k: int) -> _Programs:
         if k not in reduce_programs:
+            def mesh_partials(*parts):
+                return _reduce_partials(parts)
+
             in_specs = tuple(_point_specs(rows) for _ in range(k))
             reduce_programs[k] = _Programs(
-                "mesh_partials", mesh,
-                jax.jit(lambda *parts: _reduce_partials(parts)), in_specs)
+                "mesh_partials", mesh, jax.jit(mesh_partials), in_specs)
         return reduce_programs[k]
 
-    def call(points, digits, c: int):
+    def call(points, digits, c: int, real: int | None = None) -> bool:
         m = digits.shape[-1]
         # over-cap MSMs run as mesh slices whose [20, D] partials
         # concatenate into one final tree-sum + identity test
         step, m_to = _mesh_step(d, m)
+        _note_lanes(m if real is None else real, m_to)
         points = pad_to_multiple(points, m_to)
         digits = pad_windows(digits, m_to)
-        parts = [
-            slice_program(c)(
-                (hi - lo,), tuple(cd[..., lo:hi] for cd in points),
-                digits[:, lo:hi])
-            for lo, hi in _slices(step, m_to)
-        ]
-        return reduce_program(len(parts))((d * len(parts),), *parts)
+        with _span("mesh.msm"):
+            parts = [
+                slice_program(c)(
+                    (hi - lo,), tuple(cd[..., lo:hi] for cd in points),
+                    digits[:, lo:hi])
+                for lo, hi in _slices(step, m_to)
+            ]
+            return bool(reduce_program(len(parts))((d * len(parts),), *parts))
 
     def warm(m: int, c: int) -> list[str]:
         step, m_to = _mesh_pad(d, m)

@@ -531,8 +531,13 @@ def test_mesh_prewarm_books_hits(monkeypatch):
 
     if jax.device_count() < 2:
         pytest.skip("no multi-device mesh available")
+    from cpzk_tpu.parallel import mesh as mesh_mod
+
     monkeypatch.setattr(backend_mod, "LANE_CHUNK", 8)
     monkeypatch.setattr(backend_mod, "LANE_QUANTUM", 2)
+    # the sharded programs are the process's: start from none, as a fresh
+    # process does (earlier tests compile some of these shapes)
+    monkeypatch.setattr(mesh_mod, "_EXES", {})
     be = TpuBackend(mesh_devices=0)
     if be._mesh is None:
         pytest.skip("no multi-device mesh available")
