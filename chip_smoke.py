@@ -465,13 +465,19 @@ async def stream(client, corpus: Corpus, daemon: Daemon, first_user: int,
 
 def check_combined(recs: list[dict], bad: set[int], label: str) -> None:
     """One dispatch lane takes the stream's entries in order, so record k
-    holds entries [sum of earlier batches, + its batch): its combined check
-    must accept exactly when that range holds no invalid proof."""
+    holds entries [sum of earlier batches, + its batch): its combined check,
+    where it ran, must accept exactly when that range holds no invalid
+    proof.  A batch after one that held an invalid proof may skip it
+    (``combined`` null: the backend's combined-check gate)."""
     lo = 0
     rows = []
     for r in sorted(recs, key=lambda r: r["seq"]):
         hi = lo + r["batch"]
         valid = not any(lo <= k < hi for k in bad)
+        if r["combined"] is None:
+            rows.append(f"{r['batch']}/{r['lanes']}.{'' if valid else '!'}")
+            lo = hi
+            continue
         if r["combined"] and not valid:
             raise RuntimeError(
                 f"[{label}] the combined check accepted a batch of "
@@ -486,7 +492,8 @@ def check_combined(recs: list[dict], bad: set[int], label: str) -> None:
                     f"{'+' if r['combined'] else '-'}{'' if valid else '!'}")
         lo = hi
     info(f"combined check[{label}]: rows/lanes per batch, +/- accepted/"
-         f"rejected on device, ! holds an invalid proof: {' '.join(rows)}")
+         f"rejected on device, . skipped, ! holds an invalid proof: "
+         f"{' '.join(rows)}")
 
 
 def check_ops_plane(daemon: Daemon, recs: list[dict],

@@ -6,6 +6,8 @@ API parity: ``BatchVerifier`` accumulates up to ``MAX_BATCH_SIZE`` entries of
 single-entry batch to individual verification (batch.rs:171-183) and falling
 back to per-proof verification when the combined check fails
 (batch.rs:314-318) — so the *accept set* is always per-proof ground truth.
+After a batch that held a reject, the next batch on the same backend goes
+to the per-proof checks directly (:class:`CombinedGate`).
 
 Math fix (normative deviation, SURVEY.md §3.2): the reference's combined
 equation drops the random coefficient on the ``y^c`` term
@@ -115,6 +117,52 @@ class PreparedBatch:
     sub_prepared: "PreparedBatch | None" = field(default=None, repr=False)
 
 
+class CombinedGate:
+    """Check order for one backend: whether the next batch runs the
+    combined RLC check before the per-row checks.
+
+    Rule, with no parameters: run it first iff the previous batch
+    verified on this backend came out all-valid.  A passed combined
+    check leaves the gate open.  Otherwise ``verify_each`` runs (after a
+    failed combined check, or in place of a skipped one) and its
+    statuses decide: any invalid row (status 0 or 2) closes the gate,
+    an all-valid result opens it, since that batch's combined check
+    would have passed.  The gate starts open, so the first batch and an
+    all-valid stream run combined-first.  Verdicts never depend on it:
+    whenever the combined check does not pass, the per-row statuses
+    decide acceptance (batch.rs:314-318); a closed gate only drops a
+    check that a reject-bearing stream keeps failing.
+
+    Concurrent ``run_prepared`` calls on one backend (the pipelined
+    batcher) may race on ``open``.  The race is benign: the worst case
+    is one extra or one missing combined check, never another verdict,
+    so no lock is taken."""
+
+    __slots__ = ("open",)
+
+    def __init__(self) -> None:
+        self.open = True
+
+    def settle(self, accepted: bool | None, statuses) -> None:
+        """After a batch: ``accepted`` is the combined check's outcome
+        (None: not run), ``statuses`` the per-row ones when they ran."""
+        self.open = bool(accepted) or all(s == 1 for s in statuses)
+
+
+def _count_combined(accepted: bool | None) -> None:
+    """``batch.combined{outcome=accepted|rejected|skipped}``: one per
+    multi-row batch that could take the combined check.  Metrics live in
+    the server layer; this layer stays importable without it."""
+    try:
+        from ..server import metrics
+    except ImportError:  # pragma: no cover - server layer unavailable
+        return
+    outcome = ("skipped" if accepted is None
+               else "accepted" if accepted else "rejected")
+    metrics.counter("batch.combined", labelnames=("outcome",)).labels(
+        outcome=outcome).inc()
+
+
 class VerifierBackend:
     """Backend interface for the batch-verification compute plane.
 
@@ -136,6 +184,15 @@ class VerifierBackend:
     #: dispatcher eagerly screens deferred proofs before involving the
     #: backend, so backends never see an undecodable wire.
     supports_deferred_decode: bool = False
+
+    @property
+    def combined_gate(self) -> CombinedGate:
+        """This instance's :class:`CombinedGate`, made on first use; it
+        outlives the per-batch ``BatchVerifier`` objects."""
+        gate = self.__dict__.get("_combined_gate")
+        if gate is None:
+            gate = self.__dict__.setdefault("_combined_gate", CombinedGate())
+        return gate
 
     def verify_combined(self, rows: list[BatchRow], beta: Scalar) -> bool:
         """Corrected-RLC combined check; True iff the whole batch passes."""
@@ -644,7 +701,9 @@ class BatchVerifier:
         """Device phase: backend dispatch (``device_dispatch`` stage) and
         result assembly (``unpack``) for a :meth:`prepare_batch` output.
         Accept/reject semantics are identical to :meth:`verify` — the
-        split changes WHERE the phases run, never what they compute."""
+        split changes WHERE the phases run, never what they compute.
+        The backend's :class:`CombinedGate` decides whether a multi-row
+        batch tries the combined check before the per-row checks."""
         st = stages if stages is not None else _NULL_STAGES
         backend = self.backend
 
@@ -681,16 +740,16 @@ class BatchVerifier:
                 return [result]
 
         rows, beta = prepared.rows, prepared.beta
+        gate = backend.combined_gate
         with st.stage("device_dispatch"):
-            if (
-                prepared.same_generators
-                and backend.prefers_combined
-                and backend.verify_combined(rows, beta)
-            ):
-                statuses = None
-            else:
-                # Fallback: per-proof ground truth (batch.rs:314-318)
-                statuses = backend.verify_each(rows)
+            accepted = None  # the combined check's outcome; None: not run
+            if prepared.same_generators and backend.prefers_combined:
+                if gate.open:
+                    accepted = backend.verify_combined(rows, beta)
+                _count_combined(accepted)
+            # Fallback: per-proof ground truth (batch.rs:314-318)
+            statuses = None if accepted else backend.verify_each(rows)
+            gate.settle(accepted, statuses)
         with st.stage("unpack"):
             if statuses is None:
                 return [None] * len(rows)

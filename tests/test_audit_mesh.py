@@ -1,7 +1,9 @@
 """The audit replay on a mesh of 4 of the host's virtual devices, as the
 CLI runs it on a 4-chip host (``--backend tpu --mesh-devices 0``): the
-sharded Pippenger check then the sharded per-row fallback in every
-quantum, verdicts and report equal to the host oracle's, the sharded
+sharded Pippenger check in the first quantum of a pass and the sharded
+per-row checks in every quantum (each holds a reject, so the combined
+check's gate stays closed after the first), verdicts and report equal
+to the host oracle's, the sharded
 programs compiled once per process, and the ``mesh.*`` spans and
 counters."""
 
@@ -125,8 +127,10 @@ def test_mesh_replay_matches_the_host_oracle(runs):
         assert mesh["counts"] == cpu["counts"], k
         assert mesh["report"]["digest"] == cpu["report"]["digest"], k
         assert mesh["report"]["totals"] == cpu["report"]["totals"], k
-        # every quantum took the sharded combined check and its fallback
-        assert [f.combined for f in mesh["flights"]] == [False] * quanta
+        # the first quantum took the sharded combined check and its
+        # fallback; its reject closed the gate, so the rest went per-row
+        assert [f.combined for f in mesh["flights"]] == (
+            [False] + [None] * (quanta - 1))
 
 
 def test_second_replay_compiles_no_sharded_program(runs):
@@ -149,23 +153,25 @@ def test_mesh_spans_and_lanes(runs):
     dispatch = [s for s in spans if s.name == "device_dispatch"]
     quanta = RECORDS // QUANTUM
     assert len(dispatch) == quanta
+    # the combined check (digits, MSM) ran in the first quantum only
+    runs_in = {"mesh.digits": 1, "mesh.msm": 1, "mesh.each": quanta}
     for name in MESH_SPANS:
         mine = [s for s in spans if s.name == name]
-        assert len(mine) == quanta, name
+        assert len(mine) == runs_in[name], name
         # each sits under its quantum's device_dispatch
         for s in mine:
             assert any(o.start <= s.start and s.start + s.duration_s
                        <= o.start + o.duration_s + 1e-6 for o in dispatch), name
 
-    # the lanes the mesh programs took: 4q+2 MSM terms and q rows a
+    # the lanes the mesh programs took: 4q+2 MSM terms once and q rows a
     # quantum, padded as the slice programs pad them
     _, m_pad = backend_mod._msm_shape(QUANTUM)
     terms = 4 * QUANTUM + 2
     msm_to = mesh_mod._mesh_pad(DEVICES, m_pad)[1]
     each_to = mesh_mod._mesh_pad(DEVICES, backend_mod._pad_lanes(QUANTUM))[1]
-    assert mesh["delta"]["term"] == quanta * (terms + QUANTUM)
-    assert mesh["delta"]["pad"] == quanta * (msm_to - terms
-                                              + each_to - QUANTUM)
+    assert mesh["delta"]["term"] == terms + quanta * QUANTUM
+    assert mesh["delta"]["pad"] == (msm_to - terms
+                                    + quanta * (each_to - QUANTUM))
     assert mesh["delta"]["pad"] > 0
 
     # device_dispatch is annotated and the mesh stages inside it are not,

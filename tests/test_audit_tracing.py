@@ -48,8 +48,9 @@ def _fresh_rings():
 @pytest.fixture(scope="module")
 def proof_log(tmp_path_factory):
     path = tmp_path_factory.mktemp("audit-tracing") / "proofs.log"
-    # a wrong-secret reject in every quantum: the combined check, then the
-    # per-row fallback
+    # a wrong-secret reject in every quantum, each verified by the per-row
+    # checks (the CPU oracle prefers them; a device backend's gate would
+    # skip its combined check after the first quantum)
     assert audit_main(["generate", "--n", str(RECORDS), "--out", str(path),
                        "--reject-frac", "0.05"]) == 0
     return str(path)
