@@ -3,20 +3,15 @@
 Measures, on whatever backend JAX gives this process (the TPU on the
 chip; CPU with --platform cpu), one JSON line per configuration:
 
-- field-mul throughput for each CPZK_MUL variant (schoolbook VPU
-  outer-product vs matmul-fold MXU experiment; a Karatsuba level was
-  evaluated and removed — int32 headroom, see PROFILE.md §2);
+- field-mul throughput (the schoolbook VPU outer product);
 - point add/double throughput (XLA path vs Pallas kernels when enabled);
-- Fiat-Shamir challenge derivation (threaded native C++ vs the device
-  Keccak pipeline);
 - the two batch-verify kernels (rowcombined / pippenger) at small N.
 
-Each config runs in-process; variants toggle module globals, re-tracing
-fresh jit graphs.  Timings are best-of-ITERS wall clock around
-block_until_ready.
+Each config runs in-process.  Timings are best-of-ITERS wall clock
+around block_until_ready.
 
 Usage: python benches/bench_kernels.py [--platform cpu] [--n 65536]
-       [--iters 5] [--only mul|point|challenge|verify]
+       [--iters 5] [--only mul|point|verify]
 """
 
 from __future__ import annotations
@@ -61,24 +56,19 @@ def bench_mul(n: int, iters: int) -> None:
     a = jax.device_put(np.tile(limbs.ints_to_limbs(xs), (1, reps))[:, :n])
     b = jax.device_put(np.tile(limbs.ints_to_limbs(ys), (1, reps))[:, :n])
 
-    for variant in ("schoolbook", "matmulfold"):
-        old = limbs.MUL_VARIANT
-        limbs.MUL_VARIANT = variant
-        try:
-            # chain 8 dependent muls so timing isn't dispatch-bound
-            def chain(a, b):
-                x = limbs.mul(a, b)
-                for _ in range(7):
-                    x = limbs.mul(x, b)
-                return x
+    # chain 8 dependent muls so timing isn't dispatch-bound
+    def chain(a, b):
+        x = limbs.mul(a, b)
+        for _ in range(7):
+            x = limbs.mul(x, b)
+        return x
 
-            fn = jax.jit(chain)
-            dt = best_of(lambda: fn(a, b), iters)
-            emit(f"field_mul_{variant}", 8 * n / dt / 1e6, "Mmul/s", n=n)
-        except Exception as e:  # a variant failing to lower must not kill the run
-            emit(f"field_mul_{variant}", 0.0, "Mmul/s", n=n, error=str(e)[:200])
-        finally:
-            limbs.MUL_VARIANT = old
+    try:
+        fn = jax.jit(chain)
+        dt = best_of(lambda: fn(a, b), iters)
+        emit("field_mul_schoolbook", 8 * n / dt / 1e6, "Mmul/s", n=n)
+    except Exception as e:  # a failure to lower must not kill the run
+        emit("field_mul_schoolbook", 0.0, "Mmul/s", n=n, error=str(e)[:200])
 
 
 def _random_points(n: int):
@@ -127,75 +117,6 @@ def bench_point(n: int, iters: int) -> None:
                  error=str(e)[:200])
 
 
-def bench_challenge(n: int, iters: int) -> None:
-    """Fiat-Shamir challenge derivation: threaded native C++ (merlin.cpp)
-    vs the device Keccak pipeline (ops/challenge.py) at n rows."""
-    import os as _os
-
-    import numpy as np
-
-    from cpzk_tpu.core import _native
-
-    cols = [
-        np.frombuffer(_os.urandom(32 * n), dtype=np.uint8).reshape(n, 32).copy()
-        for _ in range(7)
-    ]
-    blobs = [c.tobytes() for c in cols]
-
-    if _native.load() is not None:
-        def native_once():
-            return _native.challenge_batch([None] * n, *blobs[1:])
-
-        native_once()
-        best = float("inf")
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            native_once()
-            best = min(best, time.perf_counter() - t0)
-        emit("challenge_native_cpp", n / best / 1e3, "kchal/s", n=n)
-
-    try:
-        # inside the guard: this import pulls jax, and a jax-less host must
-        # still emit the native number above
-        from cpzk_tpu.ops.challenge import derive_challenges_device
-
-        def device_once():
-            out = derive_challenges_device(None, *cols[1:])
-            return out
-
-        device_once()  # compile + warm; output is host numpy (blocking)
-        best = float("inf")
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            device_once()
-            best = min(best, time.perf_counter() - t0)
-        emit("challenge_device", n / best / 1e3, "kchal/s", n=n)
-
-        # fused variant: challenge bytes reduced to scalar limbs ON device
-        # (what an all-device challenges->RLC pipeline consumes directly)
-        import jax
-
-        from cpzk_tpu.ops import sclimbs
-
-        reduce_fn = jax.jit(sclimbs.reduce_wide)
-
-        def fused_once():
-            chal = derive_challenges_device(None, *cols[1:])
-            return jax.block_until_ready(
-                reduce_fn(sclimbs.bytes_wide_to_limbs(chal))
-            )
-
-        fused_once()
-        best = float("inf")
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            fused_once()
-            best = min(best, time.perf_counter() - t0)
-        emit("challenge_device_reduced", n / best / 1e3, "kchal/s", n=n)
-    except Exception as e:
-        emit("challenge_device", 0.0, "kchal/s", n=n, error=str(e)[:200])
-
-
 def bench_verify(n: int, iters: int) -> None:
     """rowcombined + pippenger end-to-end device timings at modest N —
     the same kernels bench.py guards, but runnable inline for tuning."""
@@ -226,7 +147,7 @@ def main() -> None:
     ap.add_argument("--verify-n", type=int, default=4096)
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--only", default=None,
-                    choices=(None, "mul", "point", "verify", "challenge"))
+                    choices=(None, "mul", "point", "verify"))
     args = ap.parse_args()
 
     if args.platform:
@@ -245,8 +166,6 @@ def main() -> None:
         bench_mul(args.n, args.iters)
     if args.only in (None, "point"):
         bench_point(args.n, args.iters)
-    if args.only in (None, "challenge"):
-        bench_challenge(args.n, args.iters)
     if args.only in (None, "verify"):
         bench_verify(args.verify_n, args.iters)
 

@@ -4,12 +4,11 @@ VERDICT r3 item 2: "calibrate from measurement, then delete the losers."
 The sweep (.hardware_sweep.sh) measures; this script reads its outputs
 and prints the verdicts the flags are waiting on:
 
-- CPZK_MSM_WINDOW   — best measured window vs `msm.pick_window`'s model;
-- CPZK_PIPPENGER_MIN — rowcombined/pippenger crossover from the small-N
+- CPZK_MSM_WINDOW    — best measured window vs `msm.pick_window`'s model;
+- PIPPENGER_MIN_ROWS — rowcombined/pippenger crossover from the small-N
   bench points vs the 16k/64k points;
 - CPZK_PALLAS        — graduate (make default) or drop, from the
-  point-op A/B;
-- CPZK_MUL           — same rule for the matmulfold experiment.
+  point-op A/B.
 
 Usage: python benches/calibrate.py [dir]   (default .hw)
 Prints a PROFILE.md-ready section; exits 1 when the sweep is too
@@ -97,15 +96,15 @@ def main() -> None:
     have = {**{k: v for k, v in small.items() if v},
             **{k: v for k, v in big.items() if v}}
     if have:
-        print(f"- **CPZK_PIPPENGER_MIN**: measured proofs/s by N: "
+        print(f"- **PIPPENGER_MIN_ROWS**: measured proofs/s by N: "
               f"{ {n: round(v) for n, v in sorted(have.items())} } "
               "(auto mode records the faster of rowcombined/pippenger; "
               "per-kernel rows are in the .err/.json files).")
         print("  -> set PIPPENGER_MIN_ROWS to the smallest N where the "
-              "pippenger kernel wins its A/B, and delete the env knob.")
+              "pippenger kernel wins its A/B.")
         decided += 1
     else:
-        print("- CPZK_PIPPENGER_MIN: no crossover points yet.")
+        print("- PIPPENGER_MIN_ROWS: no crossover points yet.")
 
     # 3. pallas graduate-or-drop
     xla = _value(os.path.join(d, "point_xla.json"))
@@ -120,16 +119,6 @@ def main() -> None:
         decided += 1
     else:
         print("- CPZK_PALLAS: missing point_xla/point_pallas A/B.")
-
-    # 4. mul A/B
-    mul = _value(os.path.join(d, "mul.json"))
-    if mul:
-        print(f"- **CPZK_MUL**: mul A/B recorded ({mul:.0f}); apply the "
-              "same graduate-or-drop rule from the per-config rows in "
-              "mul.json.")
-        decided += 1
-    else:
-        print("- CPZK_MUL: no mul A/B yet.")
 
     if decided == 0:
         raise SystemExit(1)

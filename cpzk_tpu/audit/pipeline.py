@@ -519,7 +519,15 @@ class _Quantum:
     records: list[dict]
     plan: list[tuple[dict, str | None, bool]]  # (rec, skip, parse_fail)
     #: the device phase: per-entry results of the live entries, in order
-    device_phase: Callable[[], list]
+    device_phase: Callable[[], list] | None
+
+    def hand_over(self) -> Callable[[], list]:
+        """The device phase, taken out of the quantum: the worker that runs
+        it holds the batch's last reference and frees the batch on its own
+        thread as the phase ends, not on the caller's between two
+        quanta's spans."""
+        phase, self.device_phase = self.device_phase, None
+        return phase
 
 
 def _overlapped(prepare, finish, stop: int, worker, trace_id: str) -> None:
@@ -530,7 +538,7 @@ def _overlapped(prepare, finish, stop: int, worker, trace_id: str) -> None:
     way the cursor stands where the serial loop would leave it."""
     tracer = get_tracer()
     q = prepare(0)
-    running = worker.submit(q.device_phase)
+    running = worker.submit(q.hand_over())
     for i in range(stop):
         nxt = failed = None
         if i + 1 < stop:
@@ -542,7 +550,7 @@ def _overlapped(prepare, finish, stop: int, worker, trace_id: str) -> None:
                          quantum=i, records=len(q.records)):
             results = running.result()
         if nxt is not None:
-            running = worker.submit(nxt.device_phase)
+            running = worker.submit(nxt.hand_over())
         finish(q, results)
         if failed is not None:
             raise failed
@@ -642,11 +650,12 @@ def _fold_quantum(
             outcome = OUTCOME_VERIFIED if computed else OUTCOME_REJECTED
             mismatch = bool(rec.get("v", 0)) != computed
             state.note(rec, outcome, mismatch=mismatch)
-    counted = metrics.counter("audit.records", labelnames=("outcome",))
-    after = (state.verified, state.rejected, state.skipped)
-    for outcome, b, a in zip(("verified", "rejected", "skipped"), before, after):
-        if a > b:
-            counted.labels(outcome=outcome).inc(a - b)
+        counted = metrics.counter("audit.records", labelnames=("outcome",))
+        after = (state.verified, state.rejected, state.skipped)
+        for outcome, b, a in zip(("verified", "rejected", "skipped"),
+                                 before, after):
+            if a > b:
+                counted.labels(outcome=outcome).inc(a - b)
 
 
 def _build_report(

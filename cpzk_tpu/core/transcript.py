@@ -136,9 +136,9 @@ def derive_challenges_batch(
     # at n=4096 and 23.3 kchal/s at n=65536 vs 383-443 kchal/s for the
     # threaded native pool below — 18-37x slower at every tier, with no
     # projected crossover (the serving plane needs ~25 kchal/s per 25k
-    # proofs/s, which one host core already triples).  The kernel itself
-    # survives as :mod:`cpzk_tpu.ops.challenge` (device Keccak-f[1600]
-    # twin, differential-tested) for silicon where the trade flips.
+    # proofs/s, which one host core already triples).  The device Keccak
+    # kernel was later deleted too; it is in git history at commit 80746aa
+    # for silicon where the trade flips.
     _warn_device_challenges_removed()
     out = _native.challenge_batch(
         contexts,

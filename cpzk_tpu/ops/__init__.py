@@ -13,8 +13,6 @@ Public surface:
 - :mod:`.verify` — per-proof and per-row combined verification kernels
 - :mod:`.msm` — windowed-Pippenger multi-scalar multiplication
 - :mod:`.prove` — fixed-base comb batch proof generation (``BatchProver``)
-- :mod:`.keccak` — batched Keccak-f[1600] permutation (hi/lo int32 lanes)
-- :mod:`.challenge` — Fiat-Shamir challenge derivation with device Keccak
 - :mod:`.backend` — the ``TpuBackend`` dispatching all of the above
 - :mod:`.pallas_kernels` — opt-in explicit-tiling kernels (``CPZK_PALLAS=1``)
 

@@ -47,10 +47,9 @@ from . import curve, msm, verify
 #: (.hw/ sweep, round 5): the per-row kernel wins EVERY measured A/B —
 #: 11,991 vs 7,844 proofs/s at n=1024, 24,714 vs 19,028 at n=4096 — so
 #: the single-device default is "never" (the Pippenger path remains the
-#: multi-chip sharded-MSM story and stays selectable via
-#: CPZK_PIPPENGER_MIN or the constructor for re-calibration on other
-#: silicon).
-PIPPENGER_MIN_ROWS = int(os.environ.get("CPZK_PIPPENGER_MIN", str(1 << 62)))
+#: multi-chip sharded-MSM story and stays selectable via the constructor's
+#: ``pippenger_min`` for re-calibration on other silicon).
+PIPPENGER_MIN_ROWS = 1 << 62
 
 #: Maximum lane count for one monolithic device program.  Measured on TPU
 #: v5 lite (round-5 sweep, PROFILE.md §7a): the MSM kernel is
@@ -450,105 +449,6 @@ def _windows(values: list[int], pad: int) -> jnp.ndarray:
     return curve.scalar_windows(values, pad)
 
 
-@jax.jit
-def _rlc_products(n_arr, al, cl, sl, bl):
-    """Device RLC scalar prep (CPZK_DEVICE_RLC=1): from alpha/challenge/
-    response limbs (zero-padded past the true row count), derive the four
-    window columns of the combined check — the per-row Python big-int
-    products this replaces are the host bottleneck at 1M-row scale
-    (PROFILE.md §1; ops/sclimbs.py module docstring).
-
-    Inputs are [20, pad] limb arrays; ``n_arr`` is the TRACED row count,
-    so the jit cache keys on the padded shape only.  The correction
-    scalars land in column ``n`` via a lane mask (matching the host
-    path's point layout: rows, then the G/H correction row, then
-    identity padding — the pre-splice padding lanes hold zero scalars).
-    Returns four [64, pad] window arrays for a, a*c, b*a, b*a*c.
-    """
-    from . import sclimbs as sc
-
-    ac = sc.mul(al, cl)
-    ba = sc.mul(bl, al)
-    bac = sc.mul(bl, ac)
-    sum_as = sc.sum_mod_l(sc.mul(al, sl))            # [20, 1]
-    corr0 = sc.neg(sum_as)
-    corr1 = sc.neg(sc.mul(bl, sum_as))
-
-    lane = jnp.arange(al.shape[-1])[None, :]  # [1, pad]
-
-    def col(body, corr):
-        spliced = jnp.where(lane == n_arr, corr, body)
-        return sc.to_windows(spliced)
-
-    zero = jnp.zeros_like(corr0)
-    return (
-        col(al, corr0), col(ac, corr1), col(ba, zero), col(bac, zero)
-    )
-
-
-def _marshal_scalar_limbs(rows: list[BatchRow], beta: Scalar, pad: int):
-    from . import sclimbs as sc
-
-    n = len(rows)
-    zeros = [0] * (pad - n)
-    al = jnp.asarray(sc.ints_to_limbs([r.alpha.value for r in rows] + zeros))
-    cl = jnp.asarray(sc.ints_to_limbs([r.c.value for r in rows] + zeros))
-    sl = jnp.asarray(sc.ints_to_limbs([r.s.value for r in rows] + zeros))
-    bl = jnp.asarray(sc.ints_to_limbs([beta.value]))
-    return al, cl, sl, bl
-
-
-def _rlc_windows_device(rows: list[BatchRow], beta: Scalar, pad: int):
-    """Device window columns for the per-row combined kernel."""
-    al, cl, sl, bl = _marshal_scalar_limbs(rows, beta, pad)
-    return _rlc_products(jnp.int32(len(rows)), al, cl, sl, bl)
-
-
-@jax.jit
-def _rlc_scalar_groups(al, cl, sl, bl):
-    """Products + corrections for the Pippenger term layout (no splice:
-    the caller concatenates the groups eagerly)."""
-    from . import sclimbs as sc
-
-    ac = sc.mul(al, cl)
-    ba = sc.mul(bl, al)
-    bac = sc.mul(bl, ac)
-    sum_as = sc.sum_mod_l(sc.mul(al, sl))
-    return ac, ba, bac, sc.neg(sum_as), sc.neg(sc.mul(bl, sum_as))
-
-
-@partial(jax.jit, static_argnums=(0,))
-def _signed_digits_jit(c, limbs_arr):
-    from . import sclimbs as sc
-
-    return sc.to_signed_digits(limbs_arr, c)
-
-
-def _pippenger_digits_device(
-    rows: list[BatchRow], beta: Scalar, m: int, c: int
-) -> jnp.ndarray:
-    """[K, m] signed digits for the 4n+2-term MSM — scalar products and
-    the digit recode both on device (CPZK_DEVICE_RLC=1 large-batch path).
-
-    Term order matches ``_combined_pippenger``'s point layout:
-    a(n) | ac(n) | ba(n) | bac(n) | corr_G | corr_H | zeros(pad).  The
-    group concatenation happens eagerly (outside jit), so the two jitted
-    stages key on the pow2-padded row count and the term count only.
-    """
-    n = len(rows)
-    pad = _pad_pow2(n)
-    al, cl, sl, bl = _marshal_scalar_limbs(rows, beta, pad)
-    ac, ba, bac, corr0, corr1 = _rlc_scalar_groups(al, cl, sl, bl)
-    from . import sclimbs as sc
-
-    zeros = jnp.zeros((sc.NLIMBS, m - 4 * n - 2), dtype=jnp.int32)
-    all_scalars = jnp.concatenate(
-        [al[:, :n], ac[:, :n], ba[:, :n], bac[:, :n], corr0, corr1, zeros],
-        axis=-1,
-    )
-    return _signed_digits_jit(c, all_scalars)
-
-
 def _each_shared_impl(n_pad, g, h, y1, y2, r1, r2, ws, wc):
     del n_pad  # static cache key only
     return verify.verify_each_kernel(g, h, y1, y2, r1, r2, ws, wc)
@@ -749,9 +649,9 @@ class TpuBackend(VerifierBackend):
                  gh_cache_max: int | None = None,
                  device=None):
         """``pippenger_min`` overrides the rowcombined->Pippenger crossover
-        for this instance (None = the module default / CPZK_PIPPENGER_MIN);
-        a constructor parameter so callers (drivers, calibration sweeps)
-        never need the env-plus-module-reload dance.  ``gh_cache_max``
+        for this instance (None = the ``PIPPENGER_MIN_ROWS`` default);
+        callers (drivers, calibration sweeps, tests) use it to drive the
+        single-device MSM.  ``gh_cache_max``
         bounds the per-generator-pair device-point cache (None = the
         GH_CACHE_MAX module default / CPZK_GH_CACHE_MAX).  ``device``
         pins the instance to one jax device (see class docstring)."""
@@ -852,12 +752,11 @@ class TpuBackend(VerifierBackend):
 
     def _verify_combined(self, rows: list[BatchRow], beta: Scalar) -> bool:
         n = len(rows)
-        device_rlc = os.environ.get("CPZK_DEVICE_RLC") == "1"
 
         if self._sharded_msm is not None or n >= self._pippenger_min:
             # a mesh always routes through the Pippenger MSM: the sharded
             # combined check is the partial-bucket-psum path (SURVEY §2.3)
-            return self._combined_pippenger(rows, beta, device_rlc)
+            return self._combined_pippenger(rows, beta)
 
         # correction row: G in slot r1 with -sum(a s), H in slot y1 with
         # -b sum(a s); identity in the other two slots.
@@ -869,38 +768,30 @@ class TpuBackend(VerifierBackend):
         y1 = _elems_soa([r.y1 for r in rows] + [rows[0].h], pad, device=dev)
         r2 = _elems_soa([r.r2 for r in rows], pad, device=dev)
         y2 = _elems_soa([r.y2 for r in rows], pad, device=dev)
-        if device_rlc:
-            _jit_first_sight("rlc", pad)
-            w_a, w_ac, w_ba, w_bac = _rlc_windows_device(rows, beta, pad)
-        else:
-            b = beta.value
-            a = [r.alpha.value for r in rows]
-            c = [r.c.value for r in rows]
-            s = [r.s.value for r in rows]
-            ac = [x * y % L for x, y in zip(a, c)]
-            ba = [b * x % L for x in a]
-            bac = [b * x % L for x in ac]
-            sum_as = sum(x * y for x, y in zip(a, s)) % L
-            w_a = _windows(a + [(L - sum_as) % L], pad)
-            w_ac = _windows(ac + [(L - b * sum_as % L) % L], pad)
-            w_ba = _windows(ba, pad)
-            w_bac = _windows(bac, pad)
+        b = beta.value
+        a = [r.alpha.value for r in rows]
+        c = [r.c.value for r in rows]
+        s = [r.s.value for r in rows]
+        ac = [x * y % L for x, y in zip(a, c)]
+        ba = [b * x % L for x in a]
+        bac = [b * x % L for x in ac]
+        sum_as = sum(x * y for x, y in zip(a, s)) % L
+        w_a = _windows(a + [(L - sum_as) % L], pad)
+        w_ac = _windows(ac + [(L - b * sum_as % L) % L], pad)
+        w_ba = _windows(ba, pad)
+        w_bac = _windows(bac, pad)
         _note_marshal(t0)
         return chunked_combined_identity(
             pad, r1, y1, r2, y2, w_a, w_ac, w_ba, w_bac)
 
-    def _combined_pippenger(
-        self, rows: list[BatchRow], beta: Scalar, device_rlc: bool
-    ) -> bool:
+    def _combined_pippenger(self, rows: list[BatchRow], beta: Scalar) -> bool:
         """One MSM over all 4n+2 (point, scalar) terms == identity.
 
         The row count (not the term count) is padded to a power of two, so
         the jit cache stays small while padding waste stays ~0% — padding
         the 4n+2 terms directly would double device work at power-of-two
-        batch sizes, the common full-batch serving case.  With
-        CPZK_DEVICE_RLC=1 the per-term scalars and their signed digits
-        come from the device scalar plane (``_pippenger_digits_device``)
-        instead of per-row host big-int products.
+        batch sizes, the common full-batch serving case.  Term order:
+        a(n) | ac(n) | ba(n) | bac(n) | corr_G | corr_H | zeros(pad).
         """
         t0 = time.perf_counter()
         elems = (
@@ -921,24 +812,21 @@ class TpuBackend(VerifierBackend):
         digits_span = (flightrec.device_span("mesh.digits") if mesh
                        else contextlib.nullcontext())
         with digits_span:
-            if device_rlc:
-                digits = _pippenger_digits_device(rows, beta, m_pad, c)
-            else:
-                b = beta.value
-                a = [r.alpha.value for r in rows]
-                ch = [r.c.value for r in rows]
-                s = [r.s.value for r in rows]
-                ac = [x * y % L for x, y in zip(a, ch)]
-                ba = [b * x % L for x in a]
-                bac = [b * x % L for x in ac]
-                sum_as = sum(x * y for x, y in zip(a, s)) % L
-                scalars = a + ac + ba + bac + [
-                    (L - sum_as) % L, (L - b * sum_as % L) % L,
-                ]
-                digits = jnp.asarray(
-                    msm.scalars_to_signed_digits(
-                        scalars + [0] * (m_pad - len(scalars)), c)
-                )
+            b = beta.value
+            a = [r.alpha.value for r in rows]
+            ch = [r.c.value for r in rows]
+            s = [r.s.value for r in rows]
+            ac = [x * y % L for x, y in zip(a, ch)]
+            ba = [b * x % L for x in a]
+            bac = [b * x % L for x in ac]
+            sum_as = sum(x * y for x, y in zip(a, s)) % L
+            scalars = a + ac + ba + bac + [
+                (L - sum_as) % L, (L - b * sum_as % L) % L,
+            ]
+            digits = jnp.asarray(
+                msm.scalars_to_signed_digits(
+                    scalars + [0] * (m_pad - len(scalars)), c)
+            )
         _note_marshal(t0)
         if mesh:
             return self._sharded_msm(pts, digits, c, real=terms)

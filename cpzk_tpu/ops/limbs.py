@@ -33,11 +33,8 @@ differential tests in ``tests/test_ops_limbs.py``.
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -250,29 +247,12 @@ def neg(a: jnp.ndarray) -> jnp.ndarray:
     return -a
 
 
-#: Multiplication strategy: "schoolbook" (default, VPU outer product +
-#: fused anti-diagonal reduce) or "matmulfold" (the fold expressed as a
-#: shared-matrix dot_general — the MXU-mapping experiment, see
-#: ``_mul_matmulfold``).  Both are bit-exact (differential tests in
-#: tests/test_ops_limbs.py).  CALIBRATED on TPU v5 lite (round-5 .hw/
-#: sweep): matmulfold +13% at n=4096 (534 vs 472 Mmul/s) but -1.5% at
-#: n=65536 (23.30 vs 23.66 GMul/s) — the MXU edge vanishes once the
-#: vector lanes fill, so schoolbook stays the default and the flag
-#: remains for A/B on other silicon.  A one-level Karatsuba variant was built and
-#: REMOVED: with the loose carried-form bound (|limb| <= ~9500) the
-#: subtractive middle product's anti-diagonal sums reach
-#: 10*(2*9500)^2 = 3.61e9 > int32, and the carry passes needed to
+#: Multiplication is the schoolbook product below.  A one-level Karatsuba
+#: variant was built and REMOVED: with the loose carried-form bound
+#: (|limb| <= ~9500) the subtractive middle product's anti-diagonal sums
+#: reach 10*(2*9500)^2 = 3.61e9 > int32, and the carry passes needed to
 #: restore headroom cost more vector ops than the 25% multiply saving
 #: buys (exact bound walk in PROFILE.md §2).
-_MUL_VARIANTS = ("schoolbook", "matmulfold")
-MUL_VARIANT = os.environ.get("CPZK_MUL", "schoolbook")
-if MUL_VARIANT not in _MUL_VARIANTS:
-    raise ValueError(
-        f"CPZK_MUL={MUL_VARIANT!r} is not one of {_MUL_VARIANTS} — refusing "
-        "to silently benchmark the default under a mislabeled name"
-    )
-
-
 def _raw_schoolbook(a: jnp.ndarray, b: jnp.ndarray, n: int) -> jnp.ndarray:
     """[n, ...] x [n, ...] -> un-carried [2n-1, ...] anti-diagonal sums.
 
@@ -292,52 +272,14 @@ def _raw_schoolbook(a: jnp.ndarray, b: jnp.ndarray, n: int) -> jnp.ndarray:
     return flat.reshape((n, 2 * n - 1) + batch).sum(axis=0)  # [2n-1, ...]
 
 
-_FOLD_MATRIX = None  # [39, 400] 0/1 anti-diagonal fold, built on first use
-
-
-def _mul_matmulfold(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Anti-diagonal fold as a shared-matrix contraction (MXU experiment).
-
-    The outer products stay elementwise (no shared contraction exists for
-    a *batched* bilinear op — the MXU fundamentally contracts a shared
-    dimension), but the fold prod[k] = sum_{i+j=k} outer[i,j] is a fixed
-    linear map F [39, 400], so ``F @ outer_flat`` CAN ride the MXU.  The
-    trade: outer_flat [400, n] must materialize through HBM (1.6 KB per
-    element per mul), so this path is expected to lose to the fused VPU
-    reduce on bandwidth — measured, not assumed (benches/bench_kernels.py,
-    PROFILE.md).
-    """
-    global _FOLD_MATRIX
-    if _FOLD_MATRIX is None:
-        # kept as numpy: it becomes an XLA constant at trace time, and a
-        # device array built inside a jit trace would leak a tracer
-        f = np.zeros((2 * NLIMBS - 1, NLIMBS * NLIMBS), dtype=np.int32)
-        for i in range(NLIMBS):
-            for j in range(NLIMBS):
-                f[i + j, i * NLIMBS + j] = 1
-        _FOLD_MATRIX = f
-    batch = a.shape[1:]
-    outer = (a[:, None] * b[None, :]).reshape((NLIMBS * NLIMBS,) + batch)
-    flat = outer.reshape(NLIMBS * NLIMBS, -1)  # [400, prod(batch)]
-    prod = jax.lax.dot_general(
-        _FOLD_MATRIX, flat, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    return prod.reshape((2 * NLIMBS - 1,) + batch)
-
-
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Batched field multiply: schoolbook 20x20 -> 39-limb product (or a
-    CPZK_MUL-selected variant), then fold+carry."""
+    """Batched field multiply: schoolbook 20x20 -> 39-limb product, then
+    fold+carry."""
     a, b = _align(a, b)
     batch = jnp.broadcast_shapes(a.shape[1:], b.shape[1:])
     a = jnp.broadcast_to(a, a.shape[:1] + batch)
     b = jnp.broadcast_to(b, b.shape[:1] + batch)
-    if MUL_VARIANT == "matmulfold":
-        prod = _mul_matmulfold(a, b)
-    else:
-        prod = _raw_schoolbook(a, b, NLIMBS)
-    return carry_product(prod)
+    return carry_product(_raw_schoolbook(a, b, NLIMBS))
 
 
 def square(a: jnp.ndarray) -> jnp.ndarray:

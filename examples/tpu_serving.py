@@ -5,12 +5,7 @@ available, any JAX backend otherwise), registers a population of users,
 then fires concurrent logins — the dynamic batcher coalesces them into
 device batches while each caller sees ordinary per-RPC semantics.
 
-Run: python examples/tpu_serving.py [--users 12] [--device-chain]
-
---device-chain additionally turns on the opt-in all-device stages
-(mod-l RLC prep on device; device Keccak challenge derivation was
-removed after round-5 calibration measured it 18-37x slower than the
-threaded native pool).
+Run: python examples/tpu_serving.py [--users 12] [--platform cpu]
 """
 
 from __future__ import annotations
@@ -79,14 +74,9 @@ async def main(n_users: int) -> None:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--users", type=int, default=12)
-    ap.add_argument("--device-chain", action="store_true",
-                    help="enable the opt-in all-device stages "
-                         "(device mod-l RLC prep)")
     ap.add_argument("--platform", default=None,
                     help="force a jax backend (e.g. cpu)")
     args = ap.parse_args()
-    if args.device_chain:
-        os.environ["CPZK_DEVICE_RLC"] = "1"
     if args.platform:
         import jax
 

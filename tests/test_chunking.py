@@ -55,59 +55,70 @@ def test_msm_shape_whole_tiles(n, m):
     assert c == backend_mod.msm.pick_window(min(m, backend_mod.LANE_CHUNK))
 
 
-def test_chunked_rowcombined_accepts_valid_batch(tiny_chunks):
-    # n+1 = 21 lanes -> 3 chunks of 8 through combined_partial_kernel
-    entries = make_entries(20)
-    assert _run(TpuBackend(), entries) == [True] * 20
-
-
-def test_chunked_rowcombined_mixed_matches_oracle(tiny_chunks):
-    entries = make_entries(20)
+def _combined(backend, entries):
+    """The backend's combined RLC verdict alone.  ``_run`` cannot see a
+    misplaced correction lane on a valid batch (the per-row fallback
+    still accepts every row), and it routes one-row batches past the
+    backend entirely."""
+    bv = BatchVerifier(backend=backend)
+    for p, st, pr in entries:
+        bv.add(p, st, pr)
     rng = SecureRng()
-    params = entries[7][0]
-    wrong = Statement.from_witness(params, Witness(Ristretto255.random_scalar(rng)))
-    entries[7] = (params, wrong, entries[7][2])
+    return backend.verify_combined(
+        bv.prepare_rows(rng), Ristretto255.random_scalar(rng))
+
+
+def _corrupt(entries, bad):
+    """Swap row ``bad``'s statement for one of a fresh witness."""
+    params = entries[bad][0]
+    wrong = Statement.from_witness(
+        params, Witness(Ristretto255.random_scalar(SecureRng())))
+    entries[bad] = (params, wrong, entries[bad][2])
+    return entries
+
+
+# With LANE_CHUNK 8 the correction row sits at lane n of _pad_lanes(n + 1):
+# in a 2-lane pad (1), at the end of chunk 1 (7), at the start / middle /
+# end of chunk 2 (8, 11, 15), and at the start / middle / end of chunk 3
+# (16, 20, 23).
+@pytest.mark.parametrize("n", [1, 7, 8, 11, 15, 16, 20, 23])
+def test_chunked_rowcombined_accepts_valid_batch(tiny_chunks, n):
+    entries = make_entries(n)
+    assert _combined(TpuBackend(), entries) is True
+    assert _run(TpuBackend(), entries) == [True] * n
+
+
+@pytest.mark.parametrize("n, bad", [
+    (20, 7), (8, 0), (8, 7), (16, 15), (23, 22),
+])
+def test_chunked_rowcombined_mixed_matches_oracle(tiny_chunks, n, bad):
+    entries = _corrupt(make_entries(n), bad)
+    assert _combined(TpuBackend(), entries) is False
     expect = _run(CpuBackend(), entries)
     # the combined check fails -> the chunked verify_each fallback decides
     assert _run(TpuBackend(), entries) == expect
-    assert expect == [i != 7 for i in range(20)]
+    assert expect == [i != bad for i in range(n)]
 
 
-def test_chunked_pippenger_accepts_valid_batch(monkeypatch):
-    # m = 4*pad_pow2(20)+2 = 130 terms -> 5 chunks of 32 through _msm_partial
+# With LANE_CHUNK 32 the 4n+2 MSM terms fill _msm_shape(n) slots: 6 and
+# 10 (one short chunk), 32 (one full chunk), 34 -> 64 (the two correction
+# terms spill past a power of two) and 128 (four full chunks).
+@pytest.mark.parametrize("n", [1, 2, 7, 8, 20])
+def test_chunked_pippenger_accepts_valid_batch(monkeypatch, n):
     monkeypatch.setattr(backend_mod, "LANE_CHUNK", 32)
-    entries = make_entries(20)
-    assert _run(TpuBackend(pippenger_min=2), entries) == [True] * 20
+    entries = make_entries(n)
+    assert _combined(TpuBackend(pippenger_min=1), entries) is True
+    assert _run(TpuBackend(pippenger_min=1), entries) == [True] * n
 
 
-def test_chunked_pippenger_mixed_matches_oracle(monkeypatch):
+@pytest.mark.parametrize("n", [8, 12])
+def test_chunked_pippenger_mixed_matches_oracle(monkeypatch, n):
     monkeypatch.setattr(backend_mod, "LANE_CHUNK", 32)
-    entries = make_entries(12)
-    rng = SecureRng()
-    params = entries[3][0]
-    wrong = Statement.from_witness(params, Witness(Ristretto255.random_scalar(rng)))
-    entries[3] = (params, wrong, entries[3][2])
+    entries = _corrupt(make_entries(n), 3)
+    assert _combined(TpuBackend(pippenger_min=2), entries) is False
     expect = _run(CpuBackend(), entries)
     assert _run(TpuBackend(pippenger_min=2), entries) == expect
-    assert expect == [i != 3 for i in range(12)]
-
-
-def test_chunked_pippenger_device_rlc(monkeypatch):
-    monkeypatch.setattr(backend_mod, "LANE_CHUNK", 32)
-    monkeypatch.setenv("CPZK_DEVICE_RLC", "1")
-    entries = make_entries(10)
-    assert _run(TpuBackend(pippenger_min=2), entries) == [True] * 10
-
-
-def test_chunked_rowcombined_device_rlc(tiny_chunks, monkeypatch):
-    """Device-RLC windows are built full-width (correction spliced at lane
-    n, possibly inside a middle chunk) and then chunk-sliced — the layout
-    must survive the tiling."""
-    monkeypatch.setenv("CPZK_DEVICE_RLC", "1")
-    entries = make_entries(20)  # correction lane lands at 20, chunk 3 of 3
-    assert _run(TpuBackend(), entries) == [True] * 20
-    entries = make_entries(11)  # correction lane 11 inside chunk 2 of 2
-    assert _run(TpuBackend(), entries) == [True] * 11
+    assert expect == [i != 3 for i in range(n)]
 
 
 def test_chunked_batch_prover(tiny_chunks):

@@ -414,12 +414,8 @@ def test_prewarm_keys_are_device_scoped(monkeypatch):
     assert backend_mod._aot_get("combined", 8) is None  # unpinned miss
 
     async def main():
-        # pippenger_min pinned: an earlier test may have reloaded the
-        # backend module with a tiny CPZK_PIPPENGER_MIN, and the prewarm
-        # plan covers the rowcombined path this test is about
         batcher = DynamicBatcher(
-            TpuBackend(device=dev, pippenger_min=1 << 62),
-            max_batch=16, window_ms=1.0,
+            TpuBackend(device=dev), max_batch=16, window_ms=1.0,
         )
         batcher.start()
         results = await batcher.submit_many(make_entries(6))
@@ -454,10 +450,8 @@ def test_prewarm_zero_compiles_on_lane_n_gt_0(monkeypatch):
     async def main():
         # pinned to lane 1's device — the lane that used to eat the
         # first-dispatch compile while the recorder booked a phantom HIT
-        # (pippenger_min pinned for the same reason as the test above)
         batcher = DynamicBatcher(
-            TpuBackend(device=devices[1], pippenger_min=1 << 62),
-            max_batch=16, window_ms=1.0,
+            TpuBackend(device=devices[1]), max_batch=16, window_ms=1.0,
         )
         batcher.start()
         results = await batcher.submit_many(make_entries(6))
