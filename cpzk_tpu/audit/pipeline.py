@@ -23,6 +23,18 @@ core and JAX's device waits release the GIL, which is what overlaps.
 before :func:`run_audit` returns or raises.  The ``lanes != 1`` router
 path has its own concurrency and runs its quanta serially.
 
+The cyclic collector is paused from the start of the log scan to the end
+of the last quantum's checkpoint, on both paths, and its previous state
+is restored on every exit (the pattern of ``ServerState.snapshot``).  A
+quantum's object graph (records, batch entries, proofs, rows, plans)
+lives until its fold and holds no reference cycle, so reference counting
+frees all of it; the collections that fired inside the loop found
+nothing there and walked the whole heap each time (JAX, the compiled
+programs, the pass's records).  ``tests/test_audit_gc.py`` pins that a
+pass of 2 quanta and one of 8 leave the same cyclic garbage, on the host
+oracle, the device backend and the router: a handful of ctypes array
+types, one per native buffer size, which ctypes caches weakly.
+
 Resumability contract (the SIGKILL test pins it exactly):
 
 - After every quantum the pipeline atomically checkpoints a **cursor**
@@ -83,12 +95,17 @@ holding the device back.
 Each single-engine dispatch books one flight record (``/flightrec``;
 ``lanes != 1`` replays through the router, whose dispatch is neither
 spanned nor recorded), and ``audit.records{outcome}`` counts records
-once per quantum.  None of it touches a verdict, the fold order or the
-report.
+once per quantum.  Each collection that runs on any thread while the
+trace is open becomes an ``audit.gc`` span (attrs ``generation`` and
+``collected``; no profiler annotation) and one
+``audit.gc.collections{generation}``: what the collector still costs
+(:class:`_Collections`).  None of it touches a verdict, the fold order
+or the report.
 """
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import json
 import os
@@ -399,6 +416,8 @@ def run_audit(
     tracer = get_tracer()
     trace_id = new_trace_id()
     tracer.start(RequestContext(trace_id=trace_id), "audit.run")
+    collections = _Collections(trace_id)
+    collector_was_on = gc.isenabled()
     status = "failure"
     try:
         with tracer.span(trace_id, "audit.open") as attrs:
@@ -420,7 +439,10 @@ def run_audit(
             )
             # ONE scan of the remaining suffix (the parse cost is linear in
             # what is left, not quadratic in batch count); quanta then slice
-            # the parsed records, with the cursor offset advanced frame-wise
+            # the parsed records, with the cursor offset advanced frame-wise.
+            # The collector stays paused from here through the last
+            # checkpoint (module doc)
+            gc.disable()
             records, valid = scan_records(
                 buf, offset=state.offset, prev_seq=state.prev_seq
             )
@@ -450,6 +472,7 @@ def run_audit(
                 _atomic_write_json(cursor_path, state.to_cursor(log_path))
             if progress is not None:
                 progress(state)
+            collections.book()
             now = time.monotonic()
             tracer.add_span(trace_id, "audit.quantum", settled_at,
                             now - settled_at, quantum=q.index,
@@ -474,6 +497,8 @@ def run_audit(
                 router.stop_thread()
             if worker is not None:
                 worker.shutdown(wait=True)
+            if collector_was_on:
+                gc.enable()
         if stop < len(quanta):
             status = "checkpointed"
             return None
@@ -496,7 +521,50 @@ def run_audit(
         status = "complete"
         return report
     finally:
+        if collector_was_on:  # a raise before the loop: the scan, or open
+            gc.enable()
+        collections.close()
         tracer.finish(trace_id, status)
+
+
+class _Collections:
+    """The cyclic collector's runs, on any thread, while one ``audit.run``
+    trace is open.  A collection can start inside any allocation, also
+    one made under the tracer's or a metric's lock on the same thread, so
+    the ``gc.callbacks`` hook takes no lock and calls neither: it only
+    appends ``(start, duration, generation, collected)`` to a plain list.
+    :meth:`book`, on the calling thread at each quantum's end and at the
+    run's, turns those into ``audit.gc`` spans and
+    ``audit.gc.collections{generation}`` counts."""
+
+    def __init__(self, trace_id: str):
+        self.trace_id = trace_id
+        self.counted = metrics.counter("audit.gc.collections",
+                                       labelnames=("generation",))
+        self.runs: list[tuple[float, float, int, int]] = []
+        self.started = 0.0
+        gc.callbacks.append(self.hook)
+
+    def hook(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.started = time.monotonic()
+        else:
+            self.runs.append((self.started, time.monotonic() - self.started,
+                              info["generation"], info["collected"]))
+
+    def book(self) -> None:
+        n = len(self.runs)
+        done = self.runs[:n]
+        del self.runs[:n]  # what a collection appends meanwhile stays
+        tracer = get_tracer()
+        for start, duration, generation, collected in done:
+            tracer.add_span(self.trace_id, "audit.gc", start, duration,
+                            generation=generation, collected=collected)
+            self.counted.labels(generation=str(generation)).inc()
+
+    def close(self) -> None:
+        gc.callbacks.remove(self.hook)
+        self.book()
 
 
 def _advance(buf: bytes, offset: int, n_frames: int) -> int:
